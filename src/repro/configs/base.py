@@ -91,9 +91,10 @@ class ArchConfig:
     lstm_bottleneck: int = 0
     input_dim: int = 0        # acoustic feature dim (paper: 260)
     # Pallas LSTM kernel knobs (repro.kernels.lstm_cell): batch tile of
-    # the (B//bB, T) grid; 0 -> auto-picked from the VMEM budget.
+    # the (B//bB, T) grid; 0 -> auto-picked from the VMEM budget, which
+    # the kernels also pass to Mosaic (plus headroom) as the VMEM limit.
     lstm_block_b: int = 0
-    lstm_vmem_budget_mb: int = 12
+    lstm_vmem_budget_mb: int = 96
     # training-forward residual stash precision ('float32' | 'bfloat16'):
     # bf16 halves the ~55MB/direction gate/cell stash at ~1e-2 normalized
     # gradient error (see kernels/lstm_cell.py 'Residual stashing').

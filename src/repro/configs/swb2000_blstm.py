@@ -24,12 +24,11 @@ SWB2000_BLSTM = register(
         lstm_hidden=512,       # per direction
         lstm_bottleneck=256,
         input_dim=260,
-        # Pallas BLSTM kernel: one direction's weights + f32 gradient
-        # accumulators are ~9.5MB resident in the backward, so the
-        # training batch tile auto-tunes to bB=64 at the 12MB budget
-        # (see kernels/lstm_cell.py docstring for the byte math).
+        # Pallas BLSTM kernel: every layer's training and inference
+        # kernels auto-tune to bB=256 (one tile per learner batch) at the
+        # 96MiB VMEM budget (kernels/lstm_cell.py docstring: byte math).
         lstm_block_b=0,        # 0 -> auto from the VMEM budget
-        lstm_vmem_budget_mb=12,
+        lstm_vmem_budget_mb=96,
         # at the paper's T=21 the per-step residual stash is cheap; for
         # long-utterance runs set lstm_seq_chunk (--seq-chunk) to trade
         # one recompute forward for an O(T/K) stash (docs/kernels.md)

@@ -57,6 +57,13 @@ Variable-length batches (the ``lengths`` key of repro.data.pipeline) are
 aggregated with *frame weights*: each learner's/microbatch's masked-mean
 gradient is scaled by its valid-frame share so uniform mixing equals the
 global masked gradient — the normative contract lives in docs/data.md.
+
+On a mesh whose learner (or, for plain data-parallel sc_psgd, batch)
+axis spans several devices, the step builders take ``shard=(mesh,
+axis)`` and compute the gradients under ``shard_map`` over that axis:
+each device runs the loss on its own learners or batch shard.  GSPMD
+cannot partition a Pallas (Mosaic) kernel by itself, so this is what
+lets ``kernel_impl="pallas"`` run on more than one chip.
 """
 from __future__ import annotations
 
@@ -67,6 +74,7 @@ from typing import Callable, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import PartitionSpec as P
 
 from repro.core.transport import Transport
 from repro.optim.optimizers import Optimizer
@@ -164,6 +172,48 @@ def _accumulated_grad(loss_fn, params, batch, n_micro: int):
         body, (g0, jnp.float32(0.0), jnp.float32(0.0)), mb)
     scale = 1.0 / jnp.maximum(wsum, 1e-6)
     return loss * scale, jax.tree.map(lambda x: x * scale, g)
+
+
+def _learner_grads(grad_one, shard):
+    """Per-learner (loss, grad) over stacked params and a pre-split batch:
+    ``vmap`` over the learner axis, under ``shard_map`` over the mesh
+    axis of ``shard = (mesh, axis)`` when given, so each device vmaps
+    only over the learners it holds."""
+    per_learner = jax.vmap(grad_one)
+    if shard is None:
+        return per_learner
+    mesh, axis = shard
+    # check_vma=False: pallas_call outputs carry no varying-axes type
+    return jax.shard_map(per_learner, mesh=mesh,
+                         in_specs=(P(axis), P(axis)),
+                         out_specs=(P(axis), P(axis)), check_vma=False)
+
+
+def _data_parallel_grad(grad_one, shard):
+    """(loss, grad) of one replicated model over a batch sharded on the
+    mesh axis of ``shard = (mesh, axis)``: each device differentiates its
+    own batch shard and the results are averaged over the axis with
+    frame weights (a masked loss is a mean over valid frames), which is
+    the mean over the whole batch.  Without ``shard`` GSPMD partitions
+    the plain gradient."""
+    if shard is None:
+        return grad_one
+    mesh, axis = shard
+
+    def local(params, batch):
+        loss, g = grad_one(params, batch)
+        w = _valid_frames(batch)
+        w = jnp.float32(1.0) if w is None else w
+        total = jax.lax.psum(w, axis)
+
+        def mean(x):
+            return (jax.lax.psum(x.astype(jnp.float32) * w, axis)
+                    / total).astype(x.dtype)
+
+        return mean(loss), jax.tree.map(mean, g)
+
+    return jax.shard_map(local, mesh=mesh, in_specs=(P(), P(axis)),
+                         out_specs=(P(), P()), check_vma=False)
 
 
 def consensus_distance(params):
@@ -307,7 +357,7 @@ def make_train_step(strategy: Strategy, loss_fn: Callable,
                     *, n_learners: int = 1, microbatches: int = 1,
                     with_consensus: bool = False, pre_split: bool = False,
                     transport: Optional[Transport] = None,
-                    with_grad_norm: bool = False):
+                    with_grad_norm: bool = False, shard=None):
     """Build the jittable train step.
 
     loss_fn(params, batch) -> scalar, over UNstacked params/batch.
@@ -335,6 +385,10 @@ def make_train_step(strategy: Strategy, loss_fn: Callable,
     strategies).  Off by default: the extra reduction changes the jit
     graph, and the observability layer's zero-overhead contract is
     that uninstrumented runs stay bit-identical.
+
+    ``shard = (mesh, axis)`` computes the gradients under ``shard_map``
+    over that mesh axis (module docstring) — the learner axis for
+    replicated strategies, the batch axis otherwise.
     """
     transport = transport if transport is not None \
         else default_transport(strategy)
@@ -344,15 +398,19 @@ def make_train_step(strategy: Strategy, loss_fn: Callable,
     def grad_one(params, batch):
         return _accumulated_grad(loss_fn, params, batch, microbatches)
 
+    learner_grads = _learner_grads(grad_one, shard)
+    data_parallel_grad = _data_parallel_grad(grad_one, shard)
+
     def step(state, batch):
         lr = lr_schedule(state["step"])
         metrics = {}
 
         if not strategy.replicated:
             # plain data-parallel SGD: gradient averaging over the data axis
-            # happens through GSPMD (batch sharded, params replicated/FSDP) —
-            # the allreduce realization of the PS (paper Eq. 13).
-            loss, g = grad_one(state["params"], batch)
+            # (GSPMD, or the psum under ``shard``; batch sharded, params
+            # replicated/FSDP) — the allreduce realization of the PS
+            # (paper Eq. 13).
+            loss, g = data_parallel_grad(state["params"], batch)
             new_params, opt = optimizer.update(g, state["opt"],
                                                state["params"], lr)
             out = {"params": new_params, "opt": opt,
@@ -364,7 +422,7 @@ def make_train_step(strategy: Strategy, loss_fn: Callable,
 
         lbatch = batch if pre_split else split_learner_batch(batch, n_learners)
         grad_at = state["prev_params"] if strategy.stale else state["params"]
-        loss_l, g_l = jax.vmap(grad_one)(grad_at, lbatch)
+        loss_l, g_l = learner_grads(grad_at, lbatch)
         if isinstance(lbatch, dict) and "lengths" in lbatch:
             # frame-weighted aggregation: each learner's masked-mean
             # gradient is scaled by its valid-frame share, so the uniform
@@ -512,7 +570,7 @@ def make_elastic_train_step(strategy: Strategy, loss_fn: Callable,
                             transport: Optional[Transport] = None,
                             fault_seed: int = 0,
                             with_corruption: bool = False,
-                            with_grad_norm: bool = False):
+                            with_grad_norm: bool = False, shard=None):
     """Build the fault-tolerant variant of :func:`make_train_step`:
 
         ``step(state, batch, faults) -> (state', metrics)``
@@ -549,7 +607,8 @@ def make_elastic_train_step(strategy: Strategy, loss_fn: Callable,
     Only replicated strategies can be elastic (non-replicated sc_psgd
     has no learner axis to mask — use ``sc_psgd_replicated``).
     Difference-coded wires (topk) are rejected by
-    :meth:`Transport.make_elastic_mixer`.
+    :meth:`Transport.make_elastic_mixer`.  ``shard`` is the learner-axis
+    ``shard_map`` of :func:`make_train_step`.
     """
     if not strategy.replicated:
         raise ValueError(
@@ -563,6 +622,8 @@ def make_elastic_train_step(strategy: Strategy, loss_fn: Callable,
 
     def grad_one(params, batch):
         return _accumulated_grad(loss_fn, params, batch, microbatches)
+
+    learner_grads = _learner_grads(grad_one, shard)
 
     def step(state, batch, faults):
         lr = lr_schedule(state["step"])
@@ -585,7 +646,7 @@ def make_elastic_train_step(strategy: Strategy, loss_fn: Callable,
         if strategy.stale:
             prev = _reseed_rejoiners(state["prev_params"], rejoin, incumbent)
             grad_at = prev
-        loss_l, g_l = jax.vmap(grad_one)(grad_at, lbatch)
+        loss_l, g_l = learner_grads(grad_at, lbatch)
 
         if isinstance(lbatch, dict) and "lengths" in lbatch:
             frames = jnp.sum(lbatch["lengths"].astype(jnp.float32),

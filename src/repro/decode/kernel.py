@@ -22,8 +22,8 @@ VMEM math (docs/decoding.md, single source :func:`beam_cand_bytes`):
 the unpruned resident set per grid step is about ``bB*V*4`` (logp)
 + ``3 * bB*K*V*4`` (base/ext/candidate grids) + small (bB, K) vectors —
 for (bB=8, K=8, V=512) about 0.5 MB — and the default ``block_b`` is
-picked by :func:`auto_block_b_decode` so the set fits the same 12 MB
-default budget the LSTM kernels use.  ``topc=C`` swaps the body for
+picked by :func:`auto_block_b_decode` so the set fits the 12 MB budget
+of kernels that keep Mosaic's default scoped VMEM limit.  ``topc=C`` swaps the body for
 ``frame_step_scores_topc``: the K-scaled grids shrink from (K, V) to
 (K, C+1) and vocab survives only in the logp block + top-C sweep
 workspace, so the VMEM ceiling (and hence ``block_b``) stops scaling
@@ -49,7 +49,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.decode.beam import (NEG, frame_step_scores,
                                frame_step_scores_topc)
-from repro.kernels.lstm_cell import (DEFAULT_VMEM_BUDGET,
+from repro.kernels.lstm_cell import (SCOPED_VMEM_BUDGET,
                                      _resolve_interpret)
 
 
@@ -70,7 +70,7 @@ def auto_block_b_decode(B: int, beam: int, vocab: int,
                         vmem_budget: int = None, topc: int = 0) -> int:
     """Largest batch tile whose beam-step resident set
     (:func:`beam_cand_bytes`) fits the budget."""
-    budget = vmem_budget or DEFAULT_VMEM_BUDGET
+    budget = vmem_budget or SCOPED_VMEM_BUDGET
     per_row = beam_cand_bytes(beam, vocab, topc)
     bb = max(1, budget // max(per_row, 1))
     return int(min(bb, B))

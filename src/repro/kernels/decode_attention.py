@@ -53,7 +53,7 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.lstm_cell import DEFAULT_VMEM_BUDGET, _resolve_interpret
+from repro.kernels.lstm_cell import SCOPED_VMEM_BUDGET, _resolve_interpret
 
 NEG_INF = -1e30
 _NO_WINDOW = 2 ** 30  # window >= S is full attention (cf. GLOBAL_WINDOW)
@@ -84,7 +84,7 @@ def auto_block_s_decode(S: int, M: int, E: int, itemsize: int = 4,
     With ``page_size`` set (paged cache) the tile is PINNED to one page —
     the physical pages are not contiguous so a tile cannot span them —
     and this only validates that a page-sized tile fits the budget."""
-    budget = vmem_budget or DEFAULT_VMEM_BUDGET
+    budget = vmem_budget or SCOPED_VMEM_BUDGET
     if page_size is not None:
         if decode_attn_vmem_bytes(page_size, M, E, itemsize) > budget:
             raise ValueError(

@@ -59,10 +59,9 @@ def _attn_kernel(q_ref, k_ref, v_ref, o_ref, *, block_k: int, seq_k: int,
 
     def body(kb, carry):
         acc, m_i, l_i = carry
-        k = pl.load(k_ref, (pl.dslice(kb * block_k, block_k),
-                            slice(None))).astype(jnp.float32)
-        v = pl.load(v_ref, (pl.dslice(kb * block_k, block_k),
-                            slice(None))).astype(jnp.float32)
+        rows = pl.ds(kb * block_k, block_k)
+        k = k_ref[rows, :].astype(jnp.float32)
+        v = v_ref[rows, :].astype(jnp.float32)
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * scale      # (bq*m, bk)

@@ -13,8 +13,6 @@ import jax
 
 from repro.kernels.flash_attention import flash_attention
 from repro.kernels.lstm_cell import blstm_sequence as _blstm_sequence
-from repro.kernels.lstm_cell import \
-    blstm_stack_sequence as _blstm_stack_sequence
 from repro.kernels.lstm_cell import lstm_sequence as _lstm_sequence
 from repro.kernels.moe_dense import moe_dense as _moe_dense
 from repro.kernels.ssd_scan import ssd as _ssd
@@ -50,21 +48,6 @@ def blstm_sequence(wx_fwd, wh_fwd, b_fwd, wx_bwd, wh_bwd, b_bwd, x,
                            lengths, block_b=block_b,
                            vmem_budget=vmem_budget,
                            stash_dtype=stash_dtype, seq_chunk=seq_chunk)
-
-
-@functools.partial(jax.jit, static_argnames=("block_b", "vmem_budget",
-                                             "stash_dtype", "seq_chunk"))
-def blstm_stack(params, x, lengths=None, *, block_b: int = None,
-                vmem_budget: int = None, stash_dtype: str = None,
-                seq_chunk: int = 0):
-    """Fused multi-layer BLSTM stack (see lstm_cell.blstm_stack_sequence):
-    ``params`` is a tuple of per-layer (wxf, whf, bf, wxb, whb, bb)
-    tuples; inference keeps inter-layer activations in VMEM, training
-    falls back to the per-layer stashing custom VJP."""
-    return _blstm_stack_sequence(params, x, lengths, block_b=block_b,
-                                 vmem_budget=vmem_budget,
-                                 stash_dtype=stash_dtype,
-                                 seq_chunk=seq_chunk)
 
 
 @functools.partial(jax.jit, static_argnames=("chunk",))

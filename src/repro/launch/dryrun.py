@@ -39,7 +39,7 @@ from repro.analysis.params import count_active_params, count_params
 from repro.analysis.roofline import model_flops, roofline_terms
 from repro.configs import ASSIGNED_ARCHS, get_arch, get_shape
 from repro.core import strategies as ST
-from repro.launch.mesh import make_production_mesh, rules_for, use_mesh
+from repro.launch.mesh import make_production_mesh, rules_for
 from repro.models import build_model
 from repro.optim.optimizers import sgd
 from repro.sharding import spec_tree_to_sds
@@ -154,7 +154,7 @@ def run_one(arch: str, shape_name: str, *, multi_pod: bool,
     rules = rules_for(cfg, mesh, multi_pod=multi_pod)
 
     t0 = time.time()
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         if shape.kind == "train":
             fn, args, meta = build_train_dryrun(cfg, mesh, rules, shape,
                                                 multi_pod=multi_pod)
@@ -177,8 +177,6 @@ def run_one(arch: str, shape_name: str, *, multi_pod: bool,
         "code_gb": ma.generated_code_size_in_bytes / 1e9,
     }
     ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):  # jax 0.4.x: one dict per computation
-        ca = ca[0] if ca else {}
     rec["cost_analysis"] = {"flops": ca.get("flops", 0.0),
                             "bytes": ca.get("bytes accessed", 0.0)}
 

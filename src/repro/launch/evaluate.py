@@ -44,7 +44,8 @@ from repro.core import strategies as ST
 from repro.data import make_dataset
 from repro.eval.metrics import (collapse_labels, frame_error_rate,
                                 greedy_ctc_decode, token_error_rate)
-from repro.launch.mesh import make_local_mesh, use_mesh
+from repro.launch.compile_cache import enable_compile_cache
+from repro.launch.mesh import make_local_mesh
 from repro.launch.train import setup_training
 from repro.models import lstm as LS
 
@@ -58,7 +59,7 @@ def restore_consensus(cfg, *, ckpt_dir: str, strategy_name: str = None,
     optimizer must match the training run), restore the checkpoint into
     it, and collapse learner replicas to the consensus params."""
     mesh = make_local_mesh()
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         state, _, meta = setup_training(
             cfg, mesh, strategy_name=strategy_name, n_learners=n_learners,
             optimizer_name=optimizer_name, kernel_impl=kernel_impl)
@@ -235,6 +236,7 @@ def main(argv=None):
                     help="strip wall-clock fields from the JSONL so "
                          "two seeded runs emit byte-identical traces")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     if args.trace_out:
         obs.configure()

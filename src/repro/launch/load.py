@@ -24,6 +24,7 @@ import time
 
 from repro import obs
 from repro.configs import get_arch
+from repro.launch.compile_cache import enable_compile_cache
 from repro.serving import (CostModel, ServingLoop, VirtualClock, WallClock,
                            Workload, generate_trace, make_payload,
                            print_csv_rows, prompt_capacity, summary_rows)
@@ -209,6 +210,7 @@ def main(argv=None):
                          "--admit-ms/--wave-ms/--work-us (implied by "
                          "--wall)")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     if args.trace_out:
         obs.configure()

@@ -15,28 +15,14 @@ axis in the H-ring multi-pod configuration.
 from __future__ import annotations
 
 import jax
-
-try:  # jax >= 0.5: explicit axis types on the mesh
-    from jax.sharding import AxisType
-except ImportError:  # jax 0.4.x: every mesh axis is implicitly 'auto'
-    AxisType = None
+from jax.sharding import AxisType
 
 from repro.sharding import MeshRules, default_rules, multipod_rules
 
 
-def _make_mesh(shape, axes):
-    if AxisType is not None:
-        return jax.make_mesh(shape, axes,
-                             axis_types=(AxisType.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
-
-
-def use_mesh(mesh):
-    """Context manager activating ``mesh``: ``jax.set_mesh`` on new jax,
-    the Mesh object's own context manager on 0.4.x."""
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    return mesh
+def _make_mesh(shape, axes, devices=None):
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes),
+                         devices=devices)
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -45,11 +31,16 @@ def make_production_mesh(*, multi_pod: bool = False):
     return _make_mesh(shape, axes)
 
 
-def make_local_mesh(data: int = 1, model: int = 1):
-    """Tiny mesh over the locally available devices (CPU tests/examples)."""
-    n = len(jax.devices())
+def make_local_mesh(data: int = 1, devices=None):
+    """Mesh over the local devices (all of them, or ``devices``): ``data``
+    of them on the 'data' axis (learners / batch shards), the rest on
+    'model'."""
+    devices = list(devices or jax.devices())
+    n = len(devices)
     data = min(data, n)
-    return _make_mesh((data, max(n // data, 1))[:2], ("data", "model"))
+    model = max(n // data, 1)
+    return _make_mesh((data, model), ("data", "model"),
+                      devices=devices[:data * model])
 
 
 def rules_for(cfg, mesh, *, multi_pod: bool = False) -> MeshRules:

@@ -51,6 +51,7 @@ from repro import decode as DC
 from repro import obs
 from repro.configs import get_arch
 from repro.configs.base import ShapeConfig
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_local_mesh, rules_for
 from repro.models import build_model
 from repro.serving.admission import (NO_BUDGET, OK, POOL_FULL,
@@ -867,6 +868,7 @@ def main(argv=None):
                     help="strip wall-clock fields from the JSONL so "
                          "two seeded runs emit byte-identical traces")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     if args.trace_out:
         obs.configure()

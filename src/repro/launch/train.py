@@ -29,8 +29,9 @@ from repro.configs import get_arch
 from repro.core import strategies as ST
 from repro.data import make_dataset
 from repro.data.pipeline import Prefetcher
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import (make_local_mesh, make_production_mesh,
-                               rules_for, use_mesh)
+                               rules_for)
 from repro.models import build_model
 from repro.optim.optimizers import get_optimizer
 from repro.optim.schedules import paper_recipe, warmup_then_anneal
@@ -56,6 +57,11 @@ def setup_training(cfg, mesh, *, strategy_name: str = None,
     argument — one ``FaultPlan.step_inputs`` dict per step — and runs
     the strategy under elastic membership with staleness-aware mixing
     (docs/fault_tolerance.md).
+
+    When the mesh axis that holds the learners (replicated strategies) or
+    the batch (plain data-parallel) spans several devices, the step
+    computes its gradients under ``shard_map`` over that axis — the only
+    way a Pallas kernel runs on more than one chip (repro.core.strategies).
     """
     strategy = ST.get_strategy(strategy_name or cfg.train_strategy)
     n_learners = n_learners if n_learners is not None else cfg.n_learners
@@ -67,6 +73,10 @@ def setup_training(cfg, mesh, *, strategy_name: str = None,
         transport = ST.transport_from_cfg(cfg, strategy)
     model = build_model(cfg)
     rules = rules_for(cfg, mesh, multi_pod=multi_pod)
+    axis = rules.rules["learner" if strategy.replicated else "batch"][0]
+    n_axis = mesh.shape.get(axis, 1)
+    shard = ((mesh, axis) if n_axis > 1 and (
+        not strategy.replicated or n_learners % n_axis == 0) else None)
     opt = get_optimizer(optimizer_name)
     lr_schedule = lr_schedule or warmup_then_anneal(0.1, 0.5, 100, 10_000,
                                                     1 / np.sqrt(2))
@@ -80,19 +90,19 @@ def setup_training(cfg, mesh, *, strategy_name: str = None,
             n_learners=n_learners, microbatches=microbatches,
             with_consensus=with_consensus, transport=transport,
             fault_seed=fault_seed, with_corruption=with_corruption,
-            with_grad_norm=with_grad_norm)
+            with_grad_norm=with_grad_norm, shard=shard)
     else:
         step_fn = ST.make_train_step(
             strategy, loss_fn, opt, lr_schedule,
             n_learners=n_learners, microbatches=microbatches,
             with_consensus=with_consensus, transport=transport,
-            with_grad_norm=with_grad_norm)
+            with_grad_norm=with_grad_norm, shard=shard)
 
     pspecs = model.param_specs()
     lead = ((n_learners, "learner"),) if strategy.replicated else ()
     param_shardings = spec_tree_shardings(pspecs, rules, extra_leading=lead)
 
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         params = init_spec_tree(pspecs, jax.random.PRNGKey(seed))
         if strategy.replicated:
             params = ST.stack_for_learners(params, n_learners)
@@ -109,7 +119,20 @@ def setup_training(cfg, mesh, *, strategy_name: str = None,
     return state, jit_step, meta
 
 
+def place_batch(batch, rules):
+    """Put a host batch on the mesh with its leading (batch) dim split by
+    the 'batch' rule, so no device holds the whole global batch."""
+    return {k: jax.device_put(
+                v, rules.sharding(v.shape, ("batch",) + (None,) * (v.ndim - 1)))
+            for k, v in batch.items()}
+
+
 def main(argv=None):
+    """Run the training CLI.  Returns the run's record for callers that
+    drive it in-process: ``losses`` (one per logged step), the final
+    ``state``, the jitted ``step``, the last placed ``batch``, ``meta``
+    of :func:`setup_training` and the step's compile/steady timer
+    ``prof``."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--strategy", default=None,
@@ -123,6 +146,9 @@ def main(argv=None):
                     help="smoke-scale variant of the arch (CPU-friendly)")
     ap.add_argument("--mesh", default="local",
                     choices=["local", "pod", "multipod"])
+    ap.add_argument("--devices", type=int, default=0,
+                    help="local mesh: how many of the local devices it "
+                         "spans, all on the 'data' axis (0 = all)")
     ap.add_argument("--ckpt-dir", default="")
     ap.add_argument("--ckpt-every", type=int, default=0)
     ap.add_argument("--log-every", type=int, default=10)
@@ -228,6 +254,7 @@ def main(argv=None):
                     help="strip wall-clock fields from the JSONL so "
                          "two seeded runs emit byte-identical traces")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     if args.trace_out:
         obs.configure()
@@ -288,7 +315,8 @@ def main(argv=None):
         print(plan.describe(), flush=True)
 
     if args.mesh == "local":
-        mesh = make_local_mesh(data=len(jax.devices()))
+        devices = jax.devices()[:args.devices or None]
+        mesh = make_local_mesh(data=len(devices), devices=devices)
     else:
         mesh = make_production_mesh(multi_pod=args.mesh == "multipod")
 
@@ -302,6 +330,12 @@ def main(argv=None):
         elastic=elastic, fault_seed=args.fault_seed,
         with_corruption=args.fault_corrupt_prob > 0,
         with_grad_norm=obs.enabled())
+    dev = jax.devices()[0]
+    print(f"mesh {dict(mesh.shape)} over {dev.platform} ({dev.device_kind}); "
+          f"kernel-impl {args.kernel_impl}"
+          + (" in Pallas interpret mode (no TPU)"
+             if args.kernel_impl == "pallas" and dev.platform != "tpu"
+             else ""), flush=True)
 
     if args.resume and not args.ckpt_dir:
         raise SystemExit("--resume needs --ckpt-dir")
@@ -343,8 +377,9 @@ def main(argv=None):
                           recorder=obs.get_recorder())
     t0 = time.time()
     valid_frames = padded_frames = 0
-    metrics = None
-    with use_mesh(meta["mesh"]):
+    metrics = batch = None
+    losses = []
+    with jax.set_mesh(meta["mesh"]):
         for k in range(start, args.steps):
             with obs.span("train/fetch", step=k):
                 batch_np = pf.next()
@@ -352,12 +387,13 @@ def main(argv=None):
                 valid_frames += int(batch_np["lengths"].sum())
                 padded_frames += (batch_np["features"].shape[0]
                                   * batch_np["features"].shape[1])
+            batch = place_batch(batch_np, meta["rules"])
             if plan is not None:
                 faults = plan.step_inputs(k)
                 ST.check_active(faults["active"])
-                state, metrics = prof(state, batch_np, faults)
+                state, metrics = prof(state, batch, faults)
             else:
-                state, metrics = prof(state, batch_np)
+                state, metrics = prof(state, batch)
             if obs.enabled():
                 scal = {k2: float(v) for k2, v in metrics.items()}
                 obs.event("train/step", step=k, **scal)
@@ -378,6 +414,7 @@ def main(argv=None):
                         valid_frames / padded_frames)
             if k % args.log_every == 0:
                 loss = float(metrics["loss"])
+                losses.append(loss)
                 line = (f"step {k:5d} loss {loss:.4f} "
                         f"({(time.time()-t0):.1f}s)")
                 if padded_frames:
@@ -419,6 +456,8 @@ def main(argv=None):
                      deterministic=args.trace_deterministic)
         print(f"trace: {n} events -> {args.trace_out}")
         obs.reset()
+    return dict(losses=losses, state=state, step=jit_step, batch=batch,
+                meta=meta, prof=prof)
 
 
 if __name__ == "__main__":

@@ -142,26 +142,30 @@ def forward(cfg, params, features, lengths=None, *,
     """features: (B, T, input_dim) -> logits (B, T, vocab).
 
     The pallas path runs the WHOLE bi-LSTM stack as one fused kernel
-    invocation (``repro.kernels.ops.blstm_stack``): inter-layer
-    activations stay VMEM-resident on the inference call, and under
-    ``jax.value_and_grad`` its custom VJP falls back to the per-layer
-    stashing forward/backward (honoring the ``lstm_stash_dtype`` /
-    ``lstm_seq_chunk`` config knobs).
+    invocation (``repro.kernels.lstm_cell.blstm_stack_sequence``):
+    inter-layer activations stay VMEM-resident on the inference call, and
+    under ``jax.value_and_grad`` its custom VJP falls back to the
+    per-layer stashing forward/backward (honoring the ``lstm_stash_dtype``
+    / ``lstm_seq_chunk`` config knobs).  It is called inside the caller's
+    trace, not through a jit wrapper: a nested jit would trace the fused
+    inference primal even under differentiation, and print its path
+    marker for a kernel the training step never runs.
 
     ``lengths`` (B,) int threads the masked recurrence through every
     layer (frozen carries + zeroed padded outputs; module docstring)."""
     x = features.astype(jnp.bfloat16)
     block_b, vmem_budget, stash_dtype, seq_chunk = _kernel_knobs(cfg)
     if kernel_impl == "pallas":
-        from repro.kernels.ops import blstm_stack
+        from repro.kernels.lstm_cell import blstm_stack_sequence
         layers = tuple(
             (p["fwd"]["wx"], p["fwd"]["wh"], p["fwd"]["b"],
              p["bwd"]["wx"], p["bwd"]["wh"], p["bwd"]["b"])
             for p in (params["layers"][f"layer_{i}"]
                       for i in range(cfg.n_layers)))
-        x = blstm_stack(layers, x, lengths, block_b=block_b,
-                        vmem_budget=vmem_budget, stash_dtype=stash_dtype,
-                        seq_chunk=seq_chunk)
+        x = blstm_stack_sequence(layers, x, lengths, block_b=block_b,
+                                 vmem_budget=vmem_budget,
+                                 stash_dtype=stash_dtype,
+                                 seq_chunk=seq_chunk)
     else:
         for i in range(cfg.n_layers):
             p = params["layers"][f"layer_{i}"]

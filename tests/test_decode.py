@@ -408,7 +408,7 @@ def test_evaluate_restores_checkpoint_end_to_end(tmp_path):
     evaluate_params reports finite TER/FER rows."""
     from repro.checkpoint import save
     from repro.launch.evaluate import evaluate_params, restore_consensus
-    from repro.launch.mesh import make_local_mesh, use_mesh
+    from repro.launch.mesh import make_local_mesh
     from repro.launch.train import setup_training
 
     cfg = _tiny_cfg()
@@ -418,7 +418,7 @@ def test_evaluate_restores_checkpoint_end_to_end(tmp_path):
     from repro.data import make_dataset
 
     ds = make_dataset(cfg, seq_len=12, batch=4, seed=0)
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         for k in range(2):
             state, _ = step_fn(state, ds.batch_at(k))
     save(str(tmp_path / "ck"), 2, state)

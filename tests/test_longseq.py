@@ -179,7 +179,7 @@ def _mk_stack(L, D0, H, base=40):
 
 
 @pytest.mark.parametrize("masked", [False, True])
-def test_blstm_stack_bitidentical(masked):
+def test_blstm_stack_bitidentical(masked, capsys):
     """Acceptance: the fused multi-layer kernel is bit-identical to the
     per-layer blstm_sequence loop (dense and masked, tiled batch with a
     non-dividing block_b), and tracks the stacked-scan oracle."""
@@ -189,6 +189,7 @@ def test_blstm_stack_bitidentical(masked):
     lens = jnp.array([9, 2, 7, 1, 5], jnp.int32) if masked else None
 
     fused = blstm_stack_sequence(layers, x, lens, interpret=True, block_b=2)
+    assert "blstm stack: fused" in capsys.readouterr().out
     loop = x
     for lw in layers:
         loop = blstm_sequence(*lw, loop, lens, interpret=True, block_b=2)
@@ -236,10 +237,10 @@ def test_auto_stack_block_b_shrinks_with_T():
     assert auto_stack_block_b(4, 8, 12, 16, 2) == 8   # tiny: one tile
 
 
-def test_stack_fallback_when_buffers_overrun_budget():
+def test_stack_fallback_when_buffers_overrun_budget(capsys):
     """When even the floor tile cannot hold the ping-pong buffers (very
-    long T for the budget), the stack primal silently degrades to the
-    per-layer loop — same numbers, T-independent VMEM."""
+    long T for the budget), the stack primal degrades to the per-layer
+    loop — same numbers, T-independent VMEM — and prints that it did."""
     B, T, D0, H, L = 4, 16, 12, 16, 2
     layers = _mk_stack(L, D0, H, base=100)
     x = _mk((B, T, D0), jnp.bfloat16, 112)
@@ -248,6 +249,7 @@ def test_stack_fallback_when_buffers_overrun_budget():
     assert _stack_usage(8, T, D0, H, 2) > tiny
     fused = blstm_stack_sequence(layers, x, interpret=True,
                                  vmem_budget=tiny)
+    assert "blstm stack: per-layer" in capsys.readouterr().out
     loop = x
     for lw in layers:
         loop = blstm_sequence(*lw, loop, interpret=True, vmem_budget=tiny)

@@ -29,6 +29,13 @@ from repro.optim.schedules import constant
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 ENV = {**os.environ, "PYTHONPATH": os.path.join(REPO, "src")}
 
+
+@pytest.fixture(scope="module", autouse=True)
+def _compile_cache_env(tmp_path_factory):
+    """The CLIs keep their compile cache under pytest's tmp dir, not in
+    the checkout."""
+    ENV["JAX_COMPILATION_CACHE_DIR"] = str(tmp_path_factory.mktemp("jax_cache"))
+
 W_TRUE = jax.random.normal(jax.random.PRNGKey(7), (8,))
 
 
