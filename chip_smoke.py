@@ -27,7 +27,9 @@ seed with the 4 learners stacked on one device, and SC-PSGD allreduce
 over 4 chips against one.  It asserts that each learner's parameter
 slice lives on its own device and that no chip holds the whole batch.
 
-Every phase prints its compile and steady seconds (information only).
+Every phase prints its compile seconds (the trainer's compile counter:
+trace, lowering, backend compile or cache load of ``train_step``) and
+its steady milliseconds per step (information only).
 The last line of standard output is one JSON object,
 ``{"ok": true, "device": {"platform": ..., "kind": ..., "count": ...}}``.
 Without a TPU, or when any check fails, the script exits non-zero and
@@ -70,10 +72,13 @@ def train(*extra: str) -> dict:
     print("== train " + " ".join(argv), flush=True)
     t0 = time.perf_counter()
     res = main(argv)
-    prof = res["prof"]
-    print(f"phase seconds: compile {prof.compile_s:.3f}, steady "
-          f"{prof.steady_s:.3f} over {prof.n_calls - prof.n_compiles} "
-          f"steps, wall {time.perf_counter() - t0:.3f}", flush=True)
+    c = res["compiles"]
+    print(f"phase seconds: compile {c['seconds']:.3f} ({c['n_compiles']} "
+          f"compile(s) of train_step: trace {c['trace_s']:.3f}, lower "
+          f"{c['lower_s']:.3f}, backend {c['compile_s']:.3f}, "
+          f"{c['cache_hits']} from the persistent cache), steady "
+          f"{res['steady_ms_per_step']:.1f} ms/step, wall "
+          f"{time.perf_counter() - t0:.3f}", flush=True)
     losses = res["losses"]
     check(len(losses) == STEPS and all(map(math.isfinite, losses)),
           f"expected {STEPS} finite losses, got {losses}")

@@ -389,6 +389,12 @@ def make_train_step(strategy: Strategy, loss_fn: Callable,
     ``shard = (mesh, axis)`` computes the gradients under ``shard_map``
     over that mesh axis (module docstring) — the learner axis for
     replicated strategies, the batch axis otherwise.
+
+    The returned function is named ``train_step``, so its jit's HLO
+    module (``jit_train_step``) and compile records say which program
+    they are; its parts run under ``jax.named_scope`` ``grad``,
+    ``mixing``, ``update``, ``grad_norm`` and ``consensus``, which land
+    in the compiled ops' metadata (docs/observability.md).
     """
     transport = transport if transport is not None \
         else default_transport(strategy)
@@ -401,7 +407,7 @@ def make_train_step(strategy: Strategy, loss_fn: Callable,
     learner_grads = _learner_grads(grad_one, shard)
     data_parallel_grad = _data_parallel_grad(grad_one, shard)
 
-    def step(state, batch):
+    def train_step(state, batch):
         lr = lr_schedule(state["step"])
         metrics = {}
 
@@ -410,19 +416,23 @@ def make_train_step(strategy: Strategy, loss_fn: Callable,
             # (GSPMD, or the psum under ``shard``; batch sharded, params
             # replicated/FSDP) — the allreduce realization of the PS
             # (paper Eq. 13).
-            loss, g = data_parallel_grad(state["params"], batch)
-            new_params, opt = optimizer.update(g, state["opt"],
-                                               state["params"], lr)
+            with jax.named_scope("grad"):
+                loss, g = data_parallel_grad(state["params"], batch)
+            with jax.named_scope("update"):
+                new_params, opt = optimizer.update(g, state["opt"],
+                                                   state["params"], lr)
             out = {"params": new_params, "opt": opt,
                    "step": state["step"] + 1}
             metrics["loss"] = loss
             if with_grad_norm:
-                metrics["grad_norm"] = _grad_norm(g)
+                with jax.named_scope("grad_norm"):
+                    metrics["grad_norm"] = _grad_norm(g)
             return out, metrics
 
         lbatch = batch if pre_split else split_learner_batch(batch, n_learners)
         grad_at = state["prev_params"] if strategy.stale else state["params"]
-        loss_l, g_l = learner_grads(grad_at, lbatch)
+        with jax.named_scope("grad"):
+            loss_l, g_l = learner_grads(grad_at, lbatch)
         if isinstance(lbatch, dict) and "lengths" in lbatch:
             # frame-weighted aggregation: each learner's masked-mean
             # gradient is scaled by its valid-frame share, so the uniform
@@ -441,7 +451,8 @@ def make_train_step(strategy: Strategy, loss_fn: Callable,
         else:
             metrics["loss"] = jnp.mean(loss_l)
         if with_grad_norm:
-            metrics["grad_norm"] = jnp.mean(_grad_norm_stacked(g_l))
+            with jax.named_scope("grad_norm"):
+                metrics["grad_norm"] = jnp.mean(_grad_norm_stacked(g_l))
 
         comm = state.get("comm", {})
         wire_bytes = jnp.float32(transport.wire_bytes(state["params"]))
@@ -449,15 +460,17 @@ def make_train_step(strategy: Strategy, loss_fn: Callable,
             # BMUF: local SGD inside a block; blockwise model-update
             # filtering at block boundaries.  The block sync goes through
             # the substrate, so e.g. int8 block sync is one config away.
-            upd_params, opt = jax.vmap(
-                optimizer.update, in_axes=(0, 0, 0, None)
-            )(g_l, state["opt"], state["params"], lr)
+            with jax.named_scope("update"):
+                upd_params, opt = jax.vmap(
+                    optimizer.update, in_axes=(0, 0, 0, None)
+                )(g_l, state["opt"], state["params"], lr)
             step_no = state["step"] + 1
             is_sync = (step_no % strategy.block_size) == 0
 
             def do_sync(args):
                 params, anchor, mom, comm = args
-                avg, comm = mix(params, step_no, comm)
+                with jax.named_scope("mixing"):
+                    avg, comm = mix(params, step_no, comm)
                 delta = jax.tree.map(
                     lambda a, b: (a.astype(jnp.float32)
                                   - b.astype(jnp.float32)), avg, anchor)
@@ -484,10 +497,12 @@ def make_train_step(strategy: Strategy, loss_fn: Callable,
             # the gradient (evaluated at prev iterate when stale) -> XLA can
             # schedule the collective concurrently with compute; chunked
             # buckets (transport.bucket_bytes) deepen that interleaving.
-            mixed, comm = mix(state["params"], state["step"], comm)
-            new_params, opt = jax.vmap(
-                optimizer.update, in_axes=(0, 0, 0, None)
-            )(g_l, state["opt"], mixed, lr)
+            with jax.named_scope("mixing"):
+                mixed, comm = mix(state["params"], state["step"], comm)
+            with jax.named_scope("update"):
+                new_params, opt = jax.vmap(
+                    optimizer.update, in_axes=(0, 0, 0, None)
+                )(g_l, state["opt"], mixed, lr)
             out = {"params": new_params, "opt": opt,
                    "step": state["step"] + 1}
             metrics["wire_bytes"] = wire_bytes
@@ -497,10 +512,11 @@ def make_train_step(strategy: Strategy, loss_fn: Callable,
         if strategy.stale:
             out["prev_params"] = state["params"]
         if with_consensus:
-            metrics["consensus"] = consensus_distance(out["params"])
+            with jax.named_scope("consensus"):
+                metrics["consensus"] = consensus_distance(out["params"])
         return out, metrics
 
-    return step
+    return train_step
 
 
 # ---------------------------------------------------------------------------
@@ -608,7 +624,8 @@ def make_elastic_train_step(strategy: Strategy, loss_fn: Callable,
     has no learner axis to mask — use ``sc_psgd_replicated``).
     Difference-coded wires (topk) are rejected by
     :meth:`Transport.make_elastic_mixer`.  ``shard`` is the learner-axis
-    ``shard_map`` of :func:`make_train_step`.
+    ``shard_map`` of :func:`make_train_step`, and the step's name and
+    scopes are its too.
     """
     if not strategy.replicated:
         raise ValueError(
@@ -625,7 +642,7 @@ def make_elastic_train_step(strategy: Strategy, loss_fn: Callable,
 
     learner_grads = _learner_grads(grad_one, shard)
 
-    def step(state, batch, faults):
+    def train_step(state, batch, faults):
         lr = lr_schedule(state["step"])
         metrics = {}
         active = faults["active"]
@@ -646,7 +663,8 @@ def make_elastic_train_step(strategy: Strategy, loss_fn: Callable,
         if strategy.stale:
             prev = _reseed_rejoiners(state["prev_params"], rejoin, incumbent)
             grad_at = prev
-        loss_l, g_l = learner_grads(grad_at, lbatch)
+        with jax.named_scope("grad"):
+            loss_l, g_l = learner_grads(grad_at, lbatch)
 
         if isinstance(lbatch, dict) and "lengths" in lbatch:
             frames = jnp.sum(lbatch["lengths"].astype(jnp.float32),
@@ -666,16 +684,18 @@ def make_elastic_train_step(strategy: Strategy, loss_fn: Callable,
         metrics["loss"] = jnp.sum(loss_l * cframes) / csum
         if with_grad_norm:
             # mean applied-gradient norm over the contributors
-            norms = _grad_norm_stacked(g_l)
-            metrics["grad_norm"] = (jnp.sum(norms * gmask)
-                                    / jnp.maximum(jnp.sum(gmask), 1.0))
+            with jax.named_scope("grad_norm"):
+                norms = _grad_norm_stacked(g_l)
+                metrics["grad_norm"] = (jnp.sum(norms * gmask)
+                                        / jnp.maximum(jnp.sum(gmask), 1.0))
 
         wire_bytes = (jnp.float32(transport.wire_bytes(params))
                       * n_act / n_learners)
 
         def elastic_mix(p, step_no):
-            return mix(p, step_no, active, staleness,
-                       faults["edge_ok"], faults["corrupt"])
+            with jax.named_scope("mixing"):
+                return mix(p, step_no, active, staleness,
+                           faults["edge_ok"], faults["corrupt"])
 
         if strategy.block_size:
             # elastic BMUF: gated local SGD inside the block; at block
@@ -686,9 +706,10 @@ def make_elastic_train_step(strategy: Strategy, loss_fn: Callable,
                        jax.tree.map(lambda m: jnp.zeros_like(m),
                                     state["block_mom"]),
                        state["block_mom"])
-            upd_params, new_opt = jax.vmap(
-                optimizer.update, in_axes=(0, 0, 0, None)
-            )(g_l, opt, params, lr)
+            with jax.named_scope("update"):
+                upd_params, new_opt = jax.vmap(
+                    optimizer.update, in_axes=(0, 0, 0, None)
+                )(g_l, opt, params, lr)
             upd_params = _sel(gmask, upd_params, params)
             new_opt = _sel(gmask, new_opt, opt)
             step_no = state["step"] + 1
@@ -717,9 +738,10 @@ def make_elastic_train_step(strategy: Strategy, loss_fn: Callable,
             metrics["wire_bytes"] = jnp.where(is_sync, wire_bytes, 0.0)
         else:
             mixed = elastic_mix(params, state["step"])
-            upd_params, new_opt = jax.vmap(
-                optimizer.update, in_axes=(0, 0, 0, None)
-            )(g_l, opt, mixed, lr)
+            with jax.named_scope("update"):
+                upd_params, new_opt = jax.vmap(
+                    optimizer.update, in_axes=(0, 0, 0, None)
+                )(g_l, opt, mixed, lr)
             # contributors step from the mixed iterate; alive
             # non-contributors keep the mixed iterate (they gossiped but
             # computed nothing); the dead stay exactly where they were
@@ -739,10 +761,12 @@ def make_elastic_train_step(strategy: Strategy, loss_fn: Callable,
         metrics["n_contrib"] = jnp.sum(gmask)
         metrics["staleness_max"] = jnp.max(out["staleness"] * (active > 0))
         if with_consensus:
-            metrics["consensus"] = _masked_consensus(out["params"], active)
+            with jax.named_scope("consensus"):
+                metrics["consensus"] = _masked_consensus(out["params"],
+                                                         active)
         return out, metrics
 
-    return step
+    return train_step
 
 
 def stack_for_learners(params, n_learners: int):

@@ -53,6 +53,8 @@ from dataclasses import dataclass
 
 import numpy as np
 
+from repro import obs
+
 
 def _rng(seed, step):
     return np.random.default_rng(np.uint64(seed * 1_000_003 + step))
@@ -298,15 +300,18 @@ class Prefetcher:
                     continue
 
     def next(self):
-        while True:
-            try:
-                return self.q.get(timeout=0.5)
-            except queue.Empty:
-                if self.error is not None:
-                    raise RuntimeError(
-                        "prefetch worker failed") from self.error
-                if not self.thread.is_alive():
-                    raise RuntimeError("prefetch worker exited unexpectedly")
+        """The next batch; the wait for it is the span ``data/wait``."""
+        with obs.span("data/wait"):
+            while True:
+                try:
+                    return self.q.get(timeout=0.5)
+                except queue.Empty:
+                    if self.error is not None:
+                        raise RuntimeError(
+                            "prefetch worker failed") from self.error
+                    if not self.thread.is_alive():
+                        raise RuntimeError(
+                            "prefetch worker exited unexpectedly")
 
     def close(self):
         self.stop.set()

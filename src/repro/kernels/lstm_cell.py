@@ -587,6 +587,7 @@ def _run_fwd(ws, x, revs, *, stash: bool, block_b, vmem_budget, interpret,
         scratch_shapes=scratch,
         compiler_params=_compiler_params(vmem_budget),
         interpret=_resolve_interpret(interpret),
+        name="blstm_fwd" if n_dir == 2 else "lstm_fwd",
     )(*operands)
     return list(outs), bb
 
@@ -759,6 +760,7 @@ def _run_bwd(wx, wh, xp, yp, acts, cseq, dyp, *, reverse: bool, bb: int,
         ],
         compiler_params=_compiler_params(vmem_budget),
         interpret=_resolve_interpret(interpret),
+        name="lstm_bwd",
     )(*operands)
 
 
@@ -928,6 +930,7 @@ def _run_bwd_chunked(wx, wh, b, xp, hbound, cbound, dyp, lens_p, *,
         ],
         compiler_params=_compiler_params(vmem_budget),
         interpret=_resolve_interpret(interpret),
+        name="lstm_bwd_chunked",
     )(dyp, xp, hbound, cbound, wx, wh, _row(b), lens_p)
 
 
@@ -1343,6 +1346,7 @@ def _stack_primal(params, x, lengths, *, interpret, block_b, vmem_budget):
         ],
         compiler_params=_compiler_params(vmem_budget),
         interpret=_resolve_interpret(interpret),
+        name="blstm_stack",
     )(*operands)
     return jnp.concatenate([yf[:, :B], yb[:, :B]], axis=-1)
 
@@ -1358,15 +1362,16 @@ def _stack_vjp_fwd(static, params, x, lengths):
     interpret, block_b, vmem_budget, stash_dtype, seq_chunk = static
     layers = _stack_layers(params)
     xl, reses = x, []
-    for (wxf, whf, bf, wxb, whb, bb_) in layers:
-        ys, res = _run_fwd_train(((wxf, whf, bf), (wxb, whb, bb_)), xl,
-                                 _BLSTM_REVS, lengths,
-                                 interpret=interpret, block_b=block_b,
-                                 vmem_budget=vmem_budget,
-                                 stash_dtype=stash_dtype,
-                                 seq_chunk=seq_chunk)
-        reses.append(res)
-        xl = jnp.concatenate(ys, axis=-1)
+    for li, (wxf, whf, bf, wxb, whb, bb_) in enumerate(layers):
+        with jax.named_scope(f"blstm_l{li}"):
+            ys, res = _run_fwd_train(((wxf, whf, bf), (wxb, whb, bb_)), xl,
+                                     _BLSTM_REVS, lengths,
+                                     interpret=interpret, block_b=block_b,
+                                     vmem_budget=vmem_budget,
+                                     stash_dtype=stash_dtype,
+                                     seq_chunk=seq_chunk)
+            reses.append(res)
+            xl = jnp.concatenate(ys, axis=-1)
     return xl, (params, lengths, tuple(reses))
 
 
@@ -1378,17 +1383,18 @@ def _stack_vjp_bwd(static, fullres, dy):
     dparams = [None] * len(layers)
     for li in reversed(range(len(layers))):
         (wxf, whf, bf, wxb, whb, bb_) = layers[li]
-        grads, dx = _run_bwd_train(
-            ((wxf, whf, bf), (wxb, whb, bb_)), reses[li],
-            (dy[..., :H], dy[..., H:]), _BLSTM_REVS,
-            interpret=interpret, block_b=block_b,
-            vmem_budget=vmem_budget, stash_dtype=stash_dtype,
-            seq_chunk=seq_chunk)
-        (dwxf, dwhf, dbf), (dwxb, dwhb, dbb) = grads
-        dparams[li] = (dwxf.astype(wxf.dtype), dwhf.astype(whf.dtype),
-                       dbf.astype(bf.dtype), dwxb.astype(wxb.dtype),
-                       dwhb.astype(whb.dtype), dbb.astype(bb_.dtype))
-        dy = dx.astype(reses[li][0].dtype)   # next layer down's cotangent
+        with jax.named_scope(f"blstm_l{li}"):
+            grads, dx = _run_bwd_train(
+                ((wxf, whf, bf), (wxb, whb, bb_)), reses[li],
+                (dy[..., :H], dy[..., H:]), _BLSTM_REVS,
+                interpret=interpret, block_b=block_b,
+                vmem_budget=vmem_budget, stash_dtype=stash_dtype,
+                seq_chunk=seq_chunk)
+            (dwxf, dwhf, dbf), (dwxb, dwhb, dbb) = grads
+            dparams[li] = (dwxf.astype(wxf.dtype), dwhf.astype(whf.dtype),
+                           dbf.astype(bf.dtype), dwxb.astype(wxb.dtype),
+                           dwhb.astype(whb.dtype), dbb.astype(bb_.dtype))
+            dy = dx.astype(reses[li][0].dtype)   # next layer's cotangent
     return tuple(dparams), dy, _len_cotangent(lengths)
 
 

@@ -63,17 +63,33 @@ def span_table(events):
 
 def compile_steady(events):
     """``fn -> phase -> (n_calls, total_s)`` for every profiled jit
-    entry point: from the ProfiledFn wall spans (which carry a
-    ``phase`` attr) when present, else from the ``profile/call_s``
-    metric snapshot.  Empty when the trace was exported
+    entry point: from the wall spans that carry a ``phase`` attr when
+    present, else from the ``profile/call_s`` metric snapshot.  A
+    ProfiledFn span is one call under the span's name; the trainer's
+    ``train/steady`` span counts its ``calls`` under its ``fn``, whose
+    compile phase then comes from the compile counter's
+    ``compile/<phase>`` spans (one compile per ``compile/compile``,
+    seconds of all three phases).  Empty when the trace was exported
     deterministically (wall records are dropped)."""
     out = defaultdict(lambda: defaultdict(lambda: [0, 0.0]))
+    counted = defaultdict(lambda: [0, 0.0])
     for ev in events:
         attrs = ev.get("attrs", {})
-        if ev.get("kind") == "span" and attrs.get("phase") in _PHASES:
-            cell = out[ev["name"]][attrs["phase"]]
-            cell[0] += 1
+        if ev.get("kind") != "span":
+            continue
+        if ev["name"].startswith("compile/") and "fn" in attrs:
+            cell = counted[attrs["fn"]]
+            cell[0] += ev["name"] == "compile/compile"
             cell[1] += float(ev.get("dur", 0.0))
+        elif attrs.get("phase") in _PHASES:
+            cell = out[attrs.get("fn", ev["name"])][attrs["phase"]]
+            cell[0] += int(attrs.get("calls", 1))
+            cell[1] += float(ev.get("dur", 0.0))
+    for fn, cell in counted.items():
+        # only entry points timed in steady state: the counter also sees
+        # every small eager computation
+        if fn in out and not out[fn]["compile"][0]:
+            out[fn]["compile"] = cell
     if out:
         return out
     for ev in events:

@@ -62,7 +62,12 @@ def setup_training(cfg, mesh, *, strategy_name: str = None,
     the batch (plain data-parallel) spans several devices, the step
     computes its gradients under ``shard_map`` over that axis — the only
     way a Pallas kernel runs on more than one chip (repro.core.strategies).
+
+    It installs the compile counter (``repro.obs.compile_records``), so
+    the step's trace, lowering and compile at its first call are
+    recorded under the name ``train_step``.
     """
+    obs.install_compile_counter()
     strategy = ST.get_strategy(strategy_name or cfg.train_strategy)
     n_learners = n_learners if n_learners is not None else cfg.n_learners
     if not strategy.replicated:
@@ -103,16 +108,20 @@ def setup_training(cfg, mesh, *, strategy_name: str = None,
     param_shardings = spec_tree_shardings(pspecs, rules, extra_leading=lead)
 
     with jax.set_mesh(mesh):
-        params = init_spec_tree(pspecs, jax.random.PRNGKey(seed))
-        if strategy.replicated:
-            params = ST.stack_for_learners(params, n_learners)
-        params = jax.tree.map(jax.device_put, params, param_shardings)
-        if elastic:
-            state = ST.init_elastic_state(strategy, params, opt,
-                                          transport=transport)
-        else:
-            state = ST.init_state(strategy, params, opt, transport=transport)
-        jit_step = jax.jit(step_fn, donate_argnums=(0,))
+        with obs.span("setup/params"):
+            params = init_spec_tree(pspecs, jax.random.PRNGKey(seed))
+            if strategy.replicated:
+                params = ST.stack_for_learners(params, n_learners)
+            params = jax.tree.map(jax.device_put, params, param_shardings)
+        with obs.span("setup/state"):
+            if elastic:
+                state = ST.init_elastic_state(strategy, params, opt,
+                                              transport=transport)
+            else:
+                state = ST.init_state(strategy, params, opt,
+                                      transport=transport)
+        with obs.span("setup/jit"):
+            jit_step = jax.jit(step_fn, donate_argnums=(0,))
 
     meta = dict(model=model, rules=rules, strategy=strategy,
                 n_learners=n_learners, mesh=mesh, transport=transport)
@@ -122,17 +131,27 @@ def setup_training(cfg, mesh, *, strategy_name: str = None,
 def place_batch(batch, rules):
     """Put a host batch on the mesh with its leading (batch) dim split by
     the 'batch' rule, so no device holds the whole global batch."""
-    return {k: jax.device_put(
-                v, rules.sharding(v.shape, ("batch",) + (None,) * (v.ndim - 1)))
-            for k, v in batch.items()}
+    with obs.span("data/place"):
+        return {k: jax.device_put(
+                    v, rules.sharding(v.shape,
+                                      ("batch",) + (None,) * (v.ndim - 1)))
+                for k, v in batch.items()}
 
 
 def main(argv=None):
     """Run the training CLI.  Returns the run's record for callers that
     drive it in-process: ``losses`` (one per logged step), the final
     ``state``, the jitted ``step``, the last placed ``batch``, ``meta``
-    of :func:`setup_training` and the step's compile/steady timer
-    ``prof``."""
+    of :func:`setup_training`, the compile counter's summary of
+    ``train_step`` over this run (``compiles``,
+    :func:`repro.obs.compile_summary`) and ``steady_ms_per_step``.
+
+    Steps are dispatched without waiting for the device: the loop reads
+    device values (``jax.device_get``) only at log steps, where it also
+    emits the interval's ``train/step`` events while tracing, at
+    checkpoints, and at the end of the run.  Steady time per step is
+    the wall time from the first logged step's outputs being ready to
+    the last step's, over the steps between."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--strategy", default=None,
@@ -365,24 +384,38 @@ def main(argv=None):
                       bucket=args.bucket)
     pf = Prefetcher(ds, start_step=start)
 
-    # compile/steady wall-time split per jit entry point: a new BATCH
-    # shape (bucketed batching pads to distinct lengths) means an XLA
-    # retrace, so key on the batch arg's array shapes (args[1])
-    def _batch_key(a, kw):
-        return tuple(sorted((k2, tuple(v.shape))
-                            for k2, v in a[1].items()))
-
-    prof = obs.ProfiledFn(jit_step, "train/step", key=_batch_key,
-                          metrics=obs.get_metrics(),
-                          recorder=obs.get_recorder())
     t0 = time.time()
     valid_frames = padded_frames = 0
     metrics = batch = None
     losses = []
+    pending = []            # (step, metrics, pad_eff) not yet read
+    first = None            # (step, perf_counter) of the first read step
+
+    def emit(rows):
+        """The interval's per-step records, read in one transfer; returns
+        the last step's values."""
+        vals = jax.device_get([m for _, m, _ in rows])
+        for (k2, _, pad_eff), scal in zip(rows, vals):
+            scal = {n: float(v) for n, v in scal.items()}
+            obs.event("train/step", step=k2, **scal)
+            obs.histogram("train/loss").observe(scal["loss"])
+            if "grad_norm" in scal:
+                obs.histogram("train/grad_norm").observe(scal["grad_norm"])
+            if "wire_bytes" in scal:
+                obs.counter("train/wire_bytes",
+                            strategy=meta["strategy"].name
+                            ).inc(scal["wire_bytes"])
+            if "n_active" in scal:
+                obs.gauge("train/n_active").set(scal["n_active"])
+                obs.histogram("train/staleness_max").observe(
+                    scal["staleness_max"])
+            if pad_eff is not None:
+                obs.gauge("train/pad_eff").set(pad_eff)
+        return vals[-1]
+
     with jax.set_mesh(meta["mesh"]):
         for k in range(start, args.steps):
-            with obs.span("train/fetch", step=k):
-                batch_np = pf.next()
+            batch_np = pf.next()
             if "lengths" in batch_np:
                 valid_frames += int(batch_np["lengths"].sum())
                 padded_frames += (batch_np["features"].shape[0]
@@ -391,29 +424,18 @@ def main(argv=None):
             if plan is not None:
                 faults = plan.step_inputs(k)
                 ST.check_active(faults["active"])
-                state, metrics = prof(state, batch, faults)
+                state, metrics = jit_step(state, batch, faults)
             else:
-                state, metrics = prof(state, batch)
+                state, metrics = jit_step(state, batch)
             if obs.enabled():
-                scal = {k2: float(v) for k2, v in metrics.items()}
-                obs.event("train/step", step=k, **scal)
-                obs.histogram("train/loss").observe(scal["loss"])
-                if "grad_norm" in scal:
-                    obs.histogram("train/grad_norm").observe(
-                        scal["grad_norm"])
-                if "wire_bytes" in scal:
-                    obs.counter("train/wire_bytes",
-                                strategy=meta["strategy"].name
-                                ).inc(scal["wire_bytes"])
-                if "n_active" in scal:
-                    obs.gauge("train/n_active").set(scal["n_active"])
-                    obs.histogram("train/staleness_max").observe(
-                        scal["staleness_max"])
-                if padded_frames:
-                    obs.gauge("train/pad_eff").set(
-                        valid_frames / padded_frames)
+                pending.append((k, metrics, valid_frames / padded_frames
+                                if padded_frames else None))
             if k % args.log_every == 0:
-                loss = float(metrics["loss"])
+                vals = emit(pending) if pending else jax.device_get(metrics)
+                pending = []
+                if first is None:
+                    first = (k, time.perf_counter())
+                loss = float(vals["loss"])
                 losses.append(loss)
                 line = (f"step {k:5d} loss {loss:.4f} "
                         f"({(time.time()-t0):.1f}s)")
@@ -421,43 +443,57 @@ def main(argv=None):
                     # padding efficiency: valid / (B * Tpad) frames —
                     # bucketing exists to push this toward 1.0
                     line += f" pad_eff {valid_frames/padded_frames:.2f}"
-                if "wire_bytes" in metrics:
+                if "wire_bytes" in vals:
                     # analytic bytes sent per learner this step
                     # (Transport.wire_bytes; docs/strategies.md)
-                    wb = float(metrics["wire_bytes"])
+                    wb = float(vals["wire_bytes"])
                     line += f" wire {wb/2**20:.2f}MB"
-                if "n_active" in metrics:
-                    line += (f" act {int(metrics['n_active'])}/"
+                if "n_active" in vals:
+                    line += (f" act {int(vals['n_active'])}/"
                              f"{meta['n_learners']}"
-                             f" stale {int(metrics['staleness_max'])}")
-                if "consensus" in metrics:
-                    line += f" consensus {float(metrics['consensus']):.3e}"
+                             f" stale {int(vals['staleness_max'])}")
+                if "consensus" in vals:
+                    line += f" consensus {float(vals['consensus']):.3e}"
                 print(line, flush=True)
             if args.ckpt_dir and args.ckpt_every and \
                     (k + 1) % args.ckpt_every == 0:
                 save(args.ckpt_dir, k + 1, state)
+        if metrics is not None:
+            jax.block_until_ready(state)
+            final = jax.device_get(metrics)
+            t_last = time.perf_counter()
+            if pending:
+                emit(pending)
     pf.close()
     if metrics is not None:
         # one parseable line for kill-and-resume / fault-smoke comparisons
-        print(f"final loss {float(metrics['loss']):.6f}")
-    # compile (first call per batch shape: trace + XLA compile) and
-    # steady-state step time are different regimes — report both
-    # instead of one conflated total (ProfiledFn split)
-    n_steady = prof.n_calls - prof.n_compiles
+        print(f"final loss {float(final['loss']):.6f}")
+    # compile (trace + lowering + XLA compile or cache load of the step,
+    # from the compile counter) and steady-state step time are different
+    # regimes: report both instead of one conflated total
+    compiles = obs.compile_summary("train_step", since=t0)
+    n_steady = args.steps - 1 - first[0] if first is not None else 0
+    steady_s = t_last - first[1] if n_steady > 0 else 0.0
+    steady_ms = 1e3 * steady_s / n_steady if n_steady > 0 else float("nan")
+    if n_steady > 0:
+        # the steady regime as one span, beside the counter's compile/*
+        # spans of the same function (repro.launch.obsreport)
+        obs.add_span("train/steady", first[1], steady_s, wall=True,
+                     fn="train_step", phase="steady", calls=n_steady)
     print(f"done: {args.steps - start} steps in {time.time()-t0:.1f}s "
           f"[{meta['strategy'].name}, L={meta['n_learners']}]")
-    print(f"timing: compile {prof.compile_s:.1f}s "
-          f"({prof.n_compiles} compile(s)), steady {prof.steady_s:.1f}s "
-          f"over {n_steady} steps"
-          + (f" ({1e3 * prof.steady_mean_s:.1f} ms/step)" if n_steady
-             else ""), flush=True)
+    print(f"timing: compile {compiles['seconds']:.1f}s "
+          f"({compiles['n_compiles']} compile(s) of train_step), steady "
+          f"{steady_s:.1f}s over {n_steady} steps"
+          + (f" ({steady_ms:.1f} ms/step)" if n_steady > 0 else ""),
+          flush=True)
     if args.trace_out:
         n = obs.dump(args.trace_out,
                      deterministic=args.trace_deterministic)
         print(f"trace: {n} events -> {args.trace_out}")
         obs.reset()
     return dict(losses=losses, state=state, step=jit_step, batch=batch,
-                meta=meta, prof=prof)
+                meta=meta, compiles=compiles, steady_ms_per_step=steady_ms)
 
 
 if __name__ == "__main__":

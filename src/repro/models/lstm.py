@@ -152,10 +152,15 @@ def forward(cfg, params, features, lengths=None, *,
     marker for a kernel the training step never runs.
 
     ``lengths`` (B,) int threads the masked recurrence through every
-    layer (frozen carries + zeroed padded outputs; module docstring)."""
+    layer (frozen carries + zeroed padded outputs; module docstring).
+
+    Each part runs under a ``jax.named_scope`` (``blstm_l{i}``,
+    ``bottleneck``, ``softmax_ce``), which lands in the op metadata of
+    the compiled step (docs/observability.md)."""
     x = features.astype(jnp.bfloat16)
     block_b, vmem_budget, stash_dtype, seq_chunk = _kernel_knobs(cfg)
     if kernel_impl == "pallas":
+        # the stack kernel's training rules scope each layer blstm_l{i}
         from repro.kernels.lstm_cell import blstm_stack_sequence
         layers = tuple(
             (p["fwd"]["wx"], p["fwd"]["wh"], p["fwd"]["b"],
@@ -169,14 +174,17 @@ def forward(cfg, params, features, lengths=None, *,
     else:
         for i in range(cfg.n_layers):
             p = params["layers"][f"layer_{i}"]
-            fwd = lstm_layer(p["fwd"], x, lengths=lengths,
-                             kernel_impl=kernel_impl)
-            bwd = lstm_layer(p["bwd"], x, lengths=lengths, reverse=True,
-                             kernel_impl=kernel_impl)
-            x = jnp.concatenate([fwd, bwd], axis=-1)
-    x = jnp.einsum("btd,dk->btk", x, params["bottleneck"])
-    logits = (jnp.einsum("btk,kv->btv", x, params["softmax_w"])
-              .astype(jnp.float32) + params["softmax_b"])
+            with jax.named_scope(f"blstm_l{i}"):
+                fwd = lstm_layer(p["fwd"], x, lengths=lengths,
+                                 kernel_impl=kernel_impl)
+                bwd = lstm_layer(p["bwd"], x, lengths=lengths,
+                                 reverse=True, kernel_impl=kernel_impl)
+                x = jnp.concatenate([fwd, bwd], axis=-1)
+    with jax.named_scope("bottleneck"):
+        x = jnp.einsum("btd,dk->btk", x, params["bottleneck"])
+    with jax.named_scope("softmax_ce"):
+        logits = (jnp.einsum("btk,kv->btv", x, params["softmax_w"])
+                  .astype(jnp.float32) + params["softmax_b"])
     return logits
 
 
@@ -187,6 +195,7 @@ def loss_train(cfg, params, batch, *, kernel_impl: str = "jax"):
     lengths = batch.get("lengths")
     logits = forward(cfg, params, batch["features"], lengths,
                      kernel_impl=kernel_impl)
-    mask = (None if lengths is None
-            else sequence_mask(lengths, logits.shape[1]))
-    return cross_entropy(logits, batch["labels"], mask=mask)
+    with jax.named_scope("softmax_ce"):
+        mask = (None if lengths is None
+                else sequence_mask(lengths, logits.shape[1]))
+        return cross_entropy(logits, batch["labels"], mask=mask)

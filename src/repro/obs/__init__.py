@@ -9,10 +9,23 @@ One process-wide pair of sinks that every surface emits through:
   schema events (spans, instants, metric snapshots) exportable as
   JSONL and as Chrome ``trace_event`` JSON.
 
+Two instruments are always on, whether or not the sinks are:
+
+* :func:`span` opens a ``jax.profiler.TraceAnnotation`` of its name, so
+  the program's spans sit on the profiler's clock beside the device
+  ops of any ``jax.profiler`` trace (a no-op while no profiler session
+  runs); it records into the flight recorder only when configured;
+* the compile counter (:mod:`repro.obs.compiles`, installed by
+  :func:`install_compile_counter`) keeps a record of every trace,
+  lowering and backend compile jax reports, behind
+  :func:`compile_records` / :func:`compile_summary`; its listeners run
+  only when jax compiles.
+
 The default is the **no-op pair**: until :func:`configure` is called
 (the launchers call it when ``--trace-out`` is passed) every
-instrument and span is a shared do-nothing object, so uninstrumented
-runs pay one method call per site and stay bit-identical — the
+instrument is a shared do-nothing object and a span only a profiler
+annotation, so uninstrumented runs pay one method call per site and
+stay bit-identical — the
 property the recovery / transport-golden / paged≡dense exactness
 tests rely on (gated by ``benchmarks/run.py --only obs`` at ≤ 3%
 step overhead).
@@ -28,6 +41,7 @@ in ``repro.serving.slo`` (which keeps deprecation shims).
 from __future__ import annotations
 
 import json
+from contextlib import contextmanager
 
 from .metrics import (  # noqa: F401  (re-exports)
     MAX_SAMPLES,
@@ -55,6 +69,11 @@ from .profile import (  # noqa: F401
     ProfiledFn,
     fit_cost_model,
     profiled,
+)
+from .compiles import (  # noqa: F401
+    install as install_compile_counter,
+    records as compile_records,
+    summary as compile_summary,
 )
 
 # ---------------------------------------------------------------------------
@@ -99,8 +118,33 @@ def event(name: str, **attrs) -> None:
     _recorder.event(name, **attrs)
 
 
+_annotation = None          # jax.profiler.TraceAnnotation, found lazily
+
+
+def _annotate(name: str):
+    global _annotation
+    if _annotation is None:
+        try:
+            from jax.profiler import TraceAnnotation
+        except ImportError:         # obs imports and runs without jax
+            from contextlib import nullcontext as TraceAnnotation
+        _annotation = TraceAnnotation
+    return _annotation(name)
+
+
+@contextmanager
+def _recorded(name: str, attrs: dict):
+    with _annotate(name), _recorder.span(name, **attrs):
+        yield
+
+
 def span(name: str, **attrs):
-    return _recorder.span(name, **attrs)
+    """Timed region: a profiler annotation of ``name`` always, and a
+    flight-recorder span with ``attrs`` while observability is
+    configured."""
+    if _recorder is NULL_RECORDER:
+        return _annotate(name)
+    return _recorded(name, attrs)
 
 
 def add_span(name: str, t0: float, dur: float, **attrs) -> None:

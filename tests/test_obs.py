@@ -303,6 +303,201 @@ def test_fit_cost_model_degenerate():
 
 
 # ---------------------------------------------------------------------------
+# the compile counter and the profiler-clock spans
+# ---------------------------------------------------------------------------
+
+def test_compile_counter_records_each_phase():
+    import jax
+    import jax.numpy as jnp
+
+    obs.install_compile_counter()
+    obs.install_compile_counter()        # idempotent: one listener pair
+
+    def _compile_probe(x):
+        return jnp.sin(x) * 2
+
+    f = jax.jit(_compile_probe)
+    f(jnp.ones(3))
+    f(jnp.ones(5))                       # a new shape compiles again
+    recs = obs.compile_records("_compile_probe")
+    assert [r["phase"] for r in recs] == ["trace", "lower", "compile"] * 2
+    assert all(r["end"] >= r["start"] and r["seconds"] >= 0
+               and r["seconds"] == pytest.approx(r["end"] - r["start"])
+               for r in recs)
+    assert [r["start"] for r in recs] == sorted(r["start"] for r in recs)
+    f(jnp.ones(3))                       # cached: jax reports nothing
+    f(jnp.ones(5))
+    assert obs.compile_records("_compile_probe") == recs
+    s = obs.compile_summary("_compile_probe")
+    assert s["n_compiles"] == 2 and s["fn"] == "_compile_probe"
+    assert s["seconds"] == pytest.approx(
+        s["trace_s"] + s["lower_s"] + s["compile_s"])
+    assert s["trace_s"] == pytest.approx(
+        sum(r["seconds"] for r in recs if r["phase"] == "trace"))
+    assert obs.compile_summary("_compile_probe",
+                               since=recs[3]["start"])["n_compiles"] == 1
+
+
+def test_compile_records_are_kept_per_function():
+    """Thousands of small eager compiles do not push out the records of
+    the train step: each function keeps its latest MAX_PER_FN."""
+    import time
+
+    from repro.obs import compiles
+
+    t = time.time()
+    trace = "/jax/core/compile/jaxpr_trace_duration"
+    compiles._on_span(trace, t, t + 2.0, fun_name="_kept_step")
+    n = 5000                             # a bench process makes more
+    for i in range(n):
+        compiles._on_span(trace, t + 3 + i, t + 3 + i, fun_name="_flood_op")
+    assert [r["seconds"] for r in obs.compile_records("_kept_step")] == \
+        [2.0]
+    flood = obs.compile_records("_flood_op")
+    assert len(flood) == compiles.MAX_PER_FN
+    assert flood[0]["start"] == t + 3 + n - compiles.MAX_PER_FN
+    both = [r["fn"] for r in obs.compile_records()
+            if r["fn"] in ("_kept_step", "_flood_op")]
+    assert both[0] == "_kept_step" and len(both) == compiles.MAX_PER_FN + 1
+
+
+def test_compile_records_are_wall_marked_spans(tmp_path):
+    import jax
+    import jax.numpy as jnp
+
+    obs.install_compile_counter()
+    obs.configure()
+
+    def _wall_probe(x):
+        return x + 1
+
+    with obs.span("outer"):
+        jax.jit(_wall_probe)(jnp.ones(2))
+    spans = [e for e in obs.get_recorder().events
+             if e["name"].startswith("compile/")
+             and e["attrs"].get("fn") == "_wall_probe"]
+    assert [e["name"] for e in spans] == ["compile/trace", "compile/lower",
+                                          "compile/compile"]
+    assert all(e.get("wall") and e["dur"] >= 0 for e in spans)
+    outer = next(e for e in obs.get_recorder().events
+                 if e["name"] == "outer")
+    assert all(e["parent"] == outer["id"] for e in spans)
+    assert all(outer["ts"] <= e["ts"] <= outer["ts"] + outer["dur"]
+               for e in spans)
+    path = tmp_path / "d.jsonl"
+    obs.dump(str(path), deterministic=True)
+    assert not any(e["name"].startswith("compile/")
+                   for e in read_jsonl(str(path)))
+
+
+def test_span_is_a_profiler_host_event(tmp_path):
+    import glob
+
+    import jax
+    import jax.numpy as jnp
+
+    x = jnp.ones(4)
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with obs.span("probe/unrecorded", step=1):    # no recorder
+            (x * 2).block_until_ready()
+        obs.configure()
+        with obs.span("probe/recorded", step=2):
+            (x * 3).block_until_ready()
+    finally:
+        jax.profiler.stop_trace()
+    files = glob.glob(str(tmp_path / "plugins" / "profile" / "*" /
+                          "*.xplane.pb"))
+    pd = jax.profiler.ProfileData.from_file(files[0])
+    host = {ev.name: ev for plane in pd.planes
+            if plane.name.startswith("/host") for line in plane.lines
+            for ev in line.events}
+    assert {"probe/unrecorded", "probe/recorded"} <= set(host)
+    assert host["probe/unrecorded"].end_ns <= host["probe/recorded"].start_ns
+    recorded = [e["name"] for e in obs.get_recorder().events]
+    assert recorded == ["probe/recorded"]
+
+
+def test_train_main_reads_the_device_only_at_log_checkpoint_and_end(
+        tmp_path, monkeypatch):
+    """The trainer dispatches every step and waits for the device only
+    where it reads a value: log steps (with the interval's per-step
+    records while tracing), checkpoints and the end of the run."""
+    import jax
+
+    from repro.launch import train
+
+    dispatched = []
+    setup = train.setup_training
+
+    def counting_setup(*a, **kw):
+        state, step, meta = setup(*a, **kw)
+
+        def counted(*args):
+            dispatched.append(1)
+            return step(*args)
+        return state, counted, meta
+
+    def spy(real, log):
+        def read(x):
+            log.append(len(dispatched))
+            return real(x)
+        return read
+
+    gets, blocks = [], []
+    monkeypatch.setattr(train, "setup_training", counting_setup)
+    monkeypatch.setattr(jax, "device_get", spy(jax.device_get, gets))
+    monkeypatch.setattr(jax, "block_until_ready",
+                        spy(jax.block_until_ready, blocks))
+    saves = []
+    save = train.save
+    monkeypatch.setattr(train, "save", lambda d, k, s: (
+        saves.append(len(dispatched)), save(d, k, s)))
+    out = tmp_path / "t.jsonl"
+    res = train.main(["--arch", "swb2000-blstm", "--reduced", "--learners",
+                      "2", "--strategy", "ad_psgd", "--steps", "7",
+                      "--log-every", "3", "--batch", "4", "--seq-len", "6",
+                      "--ckpt-dir", str(tmp_path / "ck"), "--ckpt-every",
+                      "5", "--trace-out", str(out)])
+    assert len(dispatched) == 7 and saves == [5]
+    # dispatches seen at each read: one transfer per log interval (steps
+    # 0, 3 and 6), then the end of the run; the checkpoint at step 5
+    # reads the state itself
+    assert [n for n in gets if n > 0] == [1, 4, 7, 7]
+    assert [n for n in blocks if n > 0] == [7]
+    assert len(res["losses"]) == 3
+    assert res["compiles"]["fn"] == "train_step"
+    assert res["compiles"]["n_compiles"] >= 1
+    assert res["steady_ms_per_step"] > 0
+    evs = [e for e in read_jsonl(str(out))
+           if e["kind"] == "event" and e["name"] == "train/step"]
+    assert [e["attrs"]["step"] for e in evs] == list(range(7))
+    assert all({"loss", "grad_norm", "wire_bytes"} <= set(e["attrs"])
+               for e in evs)
+
+
+def test_train_trace_fills_the_compile_steady_report(tmp_path):
+    """A traced training run gives obsreport its compile-vs-steady split
+    of ``train_step``; the deterministic export drops both halves."""
+    from repro.launch import train
+    from repro.launch.obsreport import compile_steady
+
+    argv = ["--arch", "swb2000-blstm", "--reduced", "--learners", "2",
+            "--strategy", "ad_psgd", "--steps", "5", "--log-every", "2",
+            "--batch", "4", "--seq-len", "6", "--seed", "3"]
+    out = tmp_path / "t.jsonl"
+    res = train.main(argv + ["--trace-out", str(out)])
+    prof = compile_steady(read_jsonl(str(out)))
+    assert prof["train_step"]["compile"][0] == res["compiles"]["n_compiles"]
+    n, steady_s = prof["train_step"]["steady"]
+    assert n == 4
+    assert 1e3 * steady_s / n == pytest.approx(res["steady_ms_per_step"])
+    det = tmp_path / "d.jsonl"
+    train.main(argv + ["--trace-out", str(det), "--trace-deterministic"])
+    assert not compile_steady(read_jsonl(str(det)))
+
+
+# ---------------------------------------------------------------------------
 # the module-level sinks
 # ---------------------------------------------------------------------------
 
@@ -404,6 +599,25 @@ def test_obsreport_span_attribution_and_rows():
     names = [r[0] for r in report_rows(rec.events)]
     assert "trace/events" in names and "span/outer" in names
     assert "profile/train/step/compile_s" in names
+
+
+def test_obsreport_reads_the_counter_and_steady_spans():
+    """The trainer's split: the compile counter's ``compile/*`` spans of
+    a function whose steady regime is a ``train/steady`` span; other
+    functions the counter saw are left out."""
+    from repro.launch.obsreport import compile_steady
+
+    rec = FlightRecorder()
+    rec.add_span("compile/trace", 3.0, 0.5, wall=True, fn="train_step")
+    rec.add_span("compile/lower", 3.5, 0.25, wall=True, fn="train_step")
+    rec.add_span("compile/compile", 3.75, 1.25, wall=True, fn="train_step")
+    rec.add_span("compile/compile", 5.0, 0.5, wall=True, fn="add")
+    rec.add_span("train/steady", 6.0, 3.0, wall=True, fn="train_step",
+                 phase="steady", calls=60)
+    prof3 = compile_steady(rec.events)
+    assert prof3["train_step"]["compile"] == [1, 2.0]
+    assert prof3["train_step"]["steady"] == [60, 3.0]
+    assert "add" not in prof3
 
 
 def test_obsreport_cli_rejects_invalid(tmp_path):

@@ -6,7 +6,10 @@ the kernel's limit.  These tests compile the Pallas BLSTM at the paper's
 widths (H=512 per direction, B=256, T=21, auto ``block_b``) for a
 ``v5e:2x2`` topology that is described, not attached, so they run on a
 CPU-only machine.  Nothing executes; each test asserts the Mosaic kernels
-are in the compiled program (``tpu_custom_call``).
+are in the compiled program (``tpu_custom_call``).  The last compiles the
+training cell's whole step and checks the names the device trace and the
+op metadata will carry: the kernels' (``blstm_fwd``, ``lstm_bwd``) and
+the layer scopes'.
 
 The topology is described inside a fixture, never at import: only one
 process at a time may load the TPU library, and pytest-xdist workers all
@@ -110,3 +113,77 @@ def test_blstm_stack_inference_compiles(one_chip):
     n = _kernels(lambda p, x: LC.blstm_stack_sequence(p, x, interpret=False),
                  params, x)
     assert n == 1
+
+
+def _cell_step(device, n_learners):
+    """The ring AD-PSGD train step and its argument shapes as the 1-chip
+    training cell builds them (``setup_training``'s step for 4 learners
+    of 256 x 21 frames at published widths, Pallas kernels), on one
+    described chip."""
+    import dataclasses
+
+    from repro.configs import get_arch
+    from repro.core import strategies as ST
+    from repro.launch.mesh import make_local_mesh, rules_for
+    from repro.models import build_model
+    from repro.optim.optimizers import get_optimizer
+    from repro.optim.schedules import paper_recipe
+    from repro.sharding import init_spec_tree, spec_tree_shardings
+
+    cfg = dataclasses.replace(get_arch("swb2000-blstm"),
+                              lstm_vmem_budget_mb=96)
+    mesh = make_local_mesh(data=1, devices=[device])
+    rules = rules_for(cfg, mesh)
+    strategy, opt = ST.get_strategy("ad_psgd"), get_optimizer("sgd")
+    transport = ST.transport_from_cfg(cfg, strategy)
+    model = build_model(cfg)
+    step = ST.make_train_step(
+        strategy, lambda p, b: model.loss_fn(p, b, kernel_impl="pallas"),
+        opt, paper_recipe(steps_per_epoch=1000, base_lr=0.05, peak_lr=0.2),
+        n_learners=n_learners, transport=transport)
+    pspecs = model.param_specs()
+    lead = ((n_learners, "learner"),)
+    shardings = spec_tree_shardings(pspecs, rules, extra_leading=lead)
+
+    def init():
+        params = ST.stack_for_learners(
+            init_spec_tree(pspecs, jax.random.PRNGKey(0)), n_learners)
+        return ST.init_state(strategy, params, opt, transport=transport)
+
+    one = SingleDeviceSharding(device)
+    state = {k: jax.tree.map(
+        lambda s, sh=None: _sds(sh or one, s.shape, s.dtype), v,
+        *([shardings] if k in ("params", "prev_params") else []))
+        for k, v in jax.eval_shape(init).items()}
+    rows = 256 * n_learners
+    batch = {"features": _sds(one, (rows, T, 260), jnp.float32),
+             "labels": _sds(one, (rows, T), jnp.int32)}
+    return mesh, step, state, batch
+
+
+def test_train_step_names_its_kernels_and_scopes(topo, one_chip,
+                                                 monkeypatch):
+    """The training cell's whole step: every LSTM kernel keeps its name
+    in the compiled program's instruction names (what the device trace
+    shows), and the layer scopes reach the fusions' op metadata."""
+    import re
+
+    monkeypatch.setattr(LC, "_resolve_interpret",
+                        lambda i: False if i is None else i)
+    mesh, step, state, batch = _cell_step(topo.devices[0], 4)
+    with jax.set_mesh(mesh):
+        hlo = jax.jit(step, donate_argnums=(0,)).lower(
+            state, batch).compile().as_text()
+    assert hlo.startswith("HloModule jit_train_step")
+    kernels = [re.match(r"\s*(?:ROOT )?%([\w-]+)\.\d+ = ", ln).group(1)
+               for ln in hlo.splitlines() if KERNEL in ln]
+    # six forward kernels (both directions of a layer), one backward
+    # kernel per direction and layer
+    assert sorted(kernels) == ["blstm_fwd"] * 6 + ["lstm_bwd"] * 12
+    fusions = [ln for ln in hlo.splitlines()
+               if re.match(r"\s*(?:ROOT )?%[\w.-]*fusion[\w.-]* = ", ln)]
+    for scope in ("softmax_ce", "mixing", "update", "grad", "bottleneck",
+                  "blstm_l0", "blstm_l5"):
+        # a transform wraps the scope it runs in: vmap(jvp(softmax_ce))
+        assert any(re.search(rf'op_name="[^"]*[/(]{scope}[)/]', ln)
+                   for ln in fusions), scope
