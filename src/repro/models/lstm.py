@@ -137,9 +137,11 @@ def param_specs(cfg):
     }
 
 
-def forward(cfg, params, features, lengths=None, *,
-            kernel_impl: str = "jax"):
-    """features: (B, T, input_dim) -> logits (B, T, vocab).
+def hidden(cfg, params, features, lengths=None, *,
+           kernel_impl: str = "jax"):
+    """features: (B, T, input_dim) -> bottleneck output z (B, T, K): the
+    BLSTM stack and the linear bottleneck, everything before the output
+    layer.
 
     The pallas path runs the WHOLE bi-LSTM stack as one fused kernel
     invocation (``repro.kernels.lstm_cell.blstm_stack_sequence``):
@@ -155,8 +157,8 @@ def forward(cfg, params, features, lengths=None, *,
     layer (frozen carries + zeroed padded outputs; module docstring).
 
     Each part runs under a ``jax.named_scope`` (``blstm_l{i}``,
-    ``bottleneck``, ``softmax_ce``), which lands in the op metadata of
-    the compiled step (docs/observability.md)."""
+    ``bottleneck``), which lands in the op metadata of the compiled step
+    (docs/observability.md)."""
     x = features.astype(jnp.bfloat16)
     block_b, vmem_budget, stash_dtype, seq_chunk = _kernel_knobs(cfg)
     if kernel_impl == "pallas":
@@ -181,21 +183,41 @@ def forward(cfg, params, features, lengths=None, *,
                                  reverse=True, kernel_impl=kernel_impl)
                 x = jnp.concatenate([fwd, bwd], axis=-1)
     with jax.named_scope("bottleneck"):
-        x = jnp.einsum("btd,dk->btk", x, params["bottleneck"])
+        return jnp.einsum("btd,dk->btk", x, params["bottleneck"])
+
+
+def forward(cfg, params, features, lengths=None, *,
+            kernel_impl: str = "jax"):
+    """features: (B, T, input_dim) -> logits (B, T, vocab): :func:`hidden`
+    and the output layer (scope ``softmax_ce``), for evaluation, serving
+    and decoding."""
+    z = hidden(cfg, params, features, lengths, kernel_impl=kernel_impl)
     with jax.named_scope("softmax_ce"):
-        logits = (jnp.einsum("btk,kv->btv", x, params["softmax_w"])
-                  .astype(jnp.float32) + params["softmax_b"])
-    return logits
+        return (jnp.einsum("btk,kv->btv", z, params["softmax_w"])
+                .astype(jnp.float32) + params["softmax_b"])
 
 
 def loss_train(cfg, params, batch, *, kernel_impl: str = "jax"):
     """Frame-level CE.  If the batch carries ``lengths``, padded frames are
     excluded and the loss normalizes by the valid-frame count (the masked
-    contract of ``repro.data.pipeline``)."""
-    lengths = batch.get("lengths")
-    logits = forward(cfg, params, batch["features"], lengths,
-                     kernel_impl=kernel_impl)
+    contract of ``repro.data.pipeline``).
+
+    Under ``kernel_impl="pallas"``, with a vocabulary the kernel tiles (a
+    multiple of 128), the output layer, softmax and CE gradient run as
+    one Pallas kernel (``repro.kernels.softmax_ce``) that never writes
+    the logits out; otherwise the logits of :func:`forward` go through
+    ``cross_entropy``."""
+    lengths, labels = batch.get("lengths"), batch["labels"]
+    fused = False
+    if kernel_impl == "pallas":
+        from repro.kernels import softmax_ce as SCE
+        fused = SCE.supported(cfg.vocab)
+    out = (hidden if fused else forward)(cfg, params, batch["features"],
+                                         lengths, kernel_impl=kernel_impl)
     with jax.named_scope("softmax_ce"):
         mask = (None if lengths is None
-                else sequence_mask(lengths, logits.shape[1]))
-        return cross_entropy(logits, batch["labels"], mask=mask)
+                else sequence_mask(lengths, labels.shape[1]))
+        if fused:
+            return SCE.softmax_ce(out, params["softmax_w"],
+                                  params["softmax_b"], labels, mask)
+        return cross_entropy(out, labels, mask=mask)

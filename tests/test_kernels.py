@@ -1,5 +1,7 @@
 """Per-kernel allclose vs the pure-jnp oracles (interpret mode on CPU),
 with shape/dtype sweeps."""
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -245,6 +247,130 @@ def test_lstm_pallas_loss_train_and_ad_psgd_step():
     for _ in range(2):
         state, metrics = jit_step(state, batch)
     assert np.isfinite(float(metrics["loss"]))
+
+
+# ---------------------------------------------------------------------------
+# fused output layer + softmax cross-entropy
+# ---------------------------------------------------------------------------
+
+def _ce_ref(z, w, b, labels, mask):
+    """The oracle: ``cross_entropy`` of ``z @ w + b`` with f32 logits."""
+    from repro.models.common import cross_entropy
+    return cross_entropy(jnp.dot(z, w, preferred_element_type=jnp.float32)
+                         + b, labels, mask=mask)
+
+
+@pytest.mark.parametrize("rows,V,masked,learners,primal", [
+    (200, 384, False, 0, False),     # rows off the 128-row tile
+    (200, 640, True, 0, False),
+    (96, 384, True, 3, False),       # vmap over learners, masked
+    (300, 640, False, 3, False),     # vmap, three row tiles
+    (200, 640, True, 0, True),       # the primal-only (no-grad) call
+    (96, 384, False, 3, True),
+])
+def test_softmax_ce_matches_cross_entropy(rows, V, masked, learners, primal):
+    """The fused output-layer kernel's loss and dz, dW, db against
+    value_and_grad of cross_entropy(z @ W + b)."""
+    from repro.kernels.softmax_ce import softmax_ce
+
+    K = 32
+    lead = (learners,) if learners else ()
+    z = _mk(lead + (rows, K), jnp.bfloat16, 200)
+    w = _mk(lead + (K, V), jnp.bfloat16, 201, 0.3)
+    b = _mk(lead + (V,), jnp.float32, 202, 0.1)
+    labels = jax.random.randint(jax.random.fold_in(KEY, 203), lead + (rows,),
+                                0, V, jnp.int32)
+    mask = (jnp.arange(rows) < rows - 37) if masked else None
+
+    def per_learner(f):
+        return jax.vmap(f) if learners else f
+
+    if primal:
+        got = per_learner(lambda *a: softmax_ce(*a, mask, interpret=True))(
+            z, w, b, labels)
+        want = per_learner(lambda *a: _ce_ref(*a, mask))(z, w, b, labels)
+        np.testing.assert_allclose(got, want, rtol=1e-5)
+        return
+    vg = functools.partial(jax.value_and_grad, argnums=(0, 1, 2))
+    v_k, g_k = per_learner(vg(
+        lambda z, w, b, l: softmax_ce(z, w, b, l, mask, interpret=True)))(
+            z, w, b, labels)
+    v_r, g_r = per_learner(vg(
+        lambda z, w, b, l: _ce_ref(z, w, b, l, mask)))(z, w, b, labels)
+    np.testing.assert_allclose(v_k, v_r, rtol=1e-5)
+    for got, want, name in zip(g_k, g_r, ("dz", "dW", "db")):
+        assert got.dtype == want.dtype and got.shape == want.shape, name
+        # dz and dW take the logits' gradient as a bf16 MXU operand
+        _norm_close(got, want, 1e-5 if name == "db" else 1e-2, name)
+    if masked:                          # padded frames reach no gradient
+        assert not np.any(np.asarray(g_k[0], np.float32)[..., rows - 37:, :])
+
+
+def _tiny_blstm(vocab):
+    import dataclasses
+
+    from repro.configs import get_arch
+    from repro.models import build_model
+    from repro.sharding import init_spec_tree
+
+    cfg = dataclasses.replace(get_arch("swb2000-blstm").reduced(),
+                              n_layers=1, lstm_hidden=16, lstm_bottleneck=16,
+                              input_dim=12, vocab=vocab, lstm_block_b=2)
+    model = build_model(cfg)
+    params = init_spec_tree(model.param_specs(), jax.random.PRNGKey(0))
+    B, T = 4, 5
+    batch = {
+        "features": np.asarray(_mk((B, T, cfg.input_dim), jnp.float32, 204)),
+        "labels": np.asarray(jax.random.randint(
+            jax.random.fold_in(KEY, 205), (B, T), 0, vocab, jnp.int32)),
+        "lengths": np.asarray([5, 3, 4, 5], np.int32),
+    }
+    return model, params, batch
+
+
+def test_loss_train_fused_output_layer_matches_jax():
+    """models/lstm.loss_train(kernel_impl='pallas') with a vocabulary the
+    fused output-layer kernel takes: loss and every gradient track the
+    jax path (which rounds the logits to bf16 before the bias add)."""
+    model, params, batch = _tiny_blstm(256)
+    v_j, g_j = jax.value_and_grad(
+        lambda p: model.loss_fn(p, batch, kernel_impl="jax"))(params)
+    v_p, g_p = jax.value_and_grad(
+        lambda p: model.loss_fn(p, batch, kernel_impl="pallas"))(params)
+    np.testing.assert_allclose(float(v_p), float(v_j), rtol=2e-3)
+    flat_j = jax.tree.leaves_with_path(g_j)
+    for (path, want), got in zip(flat_j, jax.tree.leaves(g_p)):
+        _norm_close(got, want, 2e-2, jax.tree_util.keystr(path))
+
+
+def test_softmax_ce_counter_counts_the_fused_path_only():
+    """``kernels/softmax_ce`` counts each trace of the fused path, by
+    rule: the training rule under value_and_grad, the primal rule for a
+    loss alone; the jax path and a vocabulary off the 128 lanes leave it
+    alone."""
+    from repro import obs
+
+    def counts():
+        return {r["tags"]["rule"]: r["value"]
+                for r in obs.get_metrics().snapshot()
+                if r["name"] == "kernels/softmax_ce"}
+
+    model, params, batch = _tiny_blstm(256)
+    obs.configure()
+    try:
+        jax.value_and_grad(
+            lambda p: model.loss_fn(p, batch, kernel_impl="jax"))(params)
+        assert counts() == {}
+        jax.value_and_grad(
+            lambda p: model.loss_fn(p, batch, kernel_impl="pallas"))(params)
+        assert counts() == {"train": 1}
+        model.loss_fn(params, batch, kernel_impl="pallas")
+        assert counts() == {"train": 1, "primal": 1}
+        model, params, batch = _tiny_blstm(200)
+        model.loss_fn(params, batch, kernel_impl="pallas")
+        assert counts() == {"train": 1, "primal": 1}
+    finally:
+        obs.reset()
 
 
 # ---------------------------------------------------------------------------

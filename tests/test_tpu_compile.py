@@ -8,8 +8,10 @@ widths (H=512 per direction, B=256, T=21, auto ``block_b``) for a
 CPU-only machine.  Nothing executes; each test asserts the Mosaic kernels
 are in the compiled program (``tpu_custom_call``).  The last compiles the
 training cell's whole step and checks the names the device trace and the
-op metadata will carry: the kernels' (``blstm_fwd``, ``lstm_bwd``) and
-the layer scopes'.
+op metadata will carry: the kernels' (``blstm_fwd``, ``lstm_bwd``,
+``softmax_ce_train``) and the layer scopes'.  The fused output layer
+(``kernels/softmax_ce.py``) compiles at the cell's 4 x 5376 frames x
+32,000 states, and the step holds no logits-sized array.
 
 The topology is described inside a fixture, never at import: only one
 process at a time may load the TPU library, and pytest-xdist workers all
@@ -23,6 +25,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.kernels import lstm_cell as LC
+from repro.kernels import softmax_ce as SCE
 
 B, T, H = 256, 21, 512
 KERNEL = 'custom_call_target="tpu_custom_call"'
@@ -115,6 +118,29 @@ def test_blstm_stack_inference_compiles(one_chip):
     assert n == 1
 
 
+@pytest.mark.parametrize("rule", ["primal", "train"])
+def test_softmax_ce_compiles(one_chip, rule):
+    """The fused output layer at the cell's shapes (4 learners x 5376
+    frames, 256 -> 32,000): its W, f32 dW accumulator and the tile's
+    f32 exponentials fit VMEM under the kernel's limit, for the loss
+    alone (primal rule) and under value_and_grad, each one Mosaic
+    kernel."""
+    L, R, K, V = 4, 256 * T, 256, 32000
+    args = (_sds(one_chip, (L, R, K), jnp.bfloat16),
+            _sds(one_chip, (L, K, V), jnp.bfloat16),
+            _sds(one_chip, (L, V), jnp.float32),
+            _sds(one_chip, (L, R), jnp.int32))
+
+    def loss(z, w, b, labels):
+        return SCE.softmax_ce(z, w, b, labels, interpret=False)
+
+    if rule == "train":
+        loss = jax.value_and_grad(loss, argnums=(0, 1, 2))
+    hlo = jax.jit(jax.vmap(loss)).lower(*args).compile().as_text()
+    assert hlo.count(KERNEL) == 1
+    assert "softmax_ce_train" in hlo
+
+
 def _cell_step(device, n_learners):
     """The ring AD-PSGD train step and its argument shapes as the 1-chip
     training cell builds them (``setup_training``'s step for 4 learners
@@ -163,13 +189,17 @@ def _cell_step(device, n_learners):
 
 def test_train_step_names_its_kernels_and_scopes(topo, one_chip,
                                                  monkeypatch):
-    """The training cell's whole step: every LSTM kernel keeps its name
-    in the compiled program's instruction names (what the device trace
-    shows), and the layer scopes reach the fusions' op metadata."""
+    """The training cell's whole step: every kernel keeps its name in
+    the compiled program's instruction names (what the device trace
+    shows), the layer scopes reach the op metadata of its fusions (the
+    output layer's, of its kernel too), and no array the size of the logits is left: none ends in
+    the 32,000 states with more elements than the learners' dW."""
+    import math
     import re
 
-    monkeypatch.setattr(LC, "_resolve_interpret",
-                        lambda i: False if i is None else i)
+    for kernels in (LC, SCE):
+        monkeypatch.setattr(kernels, "_resolve_interpret",
+                            lambda i: False if i is None else i)
     mesh, step, state, batch = _cell_step(topo.devices[0], 4)
     with jax.set_mesh(mesh):
         hlo = jax.jit(step, donate_argnums=(0,)).lower(
@@ -178,12 +208,25 @@ def test_train_step_names_its_kernels_and_scopes(topo, one_chip,
     kernels = [re.match(r"\s*(?:ROOT )?%([\w-]+)\.\d+ = ", ln).group(1)
                for ln in hlo.splitlines() if KERNEL in ln]
     # six forward kernels (both directions of a layer), one backward
-    # kernel per direction and layer
-    assert sorted(kernels) == ["blstm_fwd"] * 6 + ["lstm_bwd"] * 12
+    # kernel per direction and layer, the fused output layer
+    assert sorted(kernels) == (["blstm_fwd"] * 6 + ["lstm_bwd"] * 12
+                               + ["softmax_ce_train"])
     fusions = [ln for ln in hlo.splitlines()
                if re.match(r"\s*(?:ROOT )?%[\w.-]*fusion[\w.-]* = ", ln)]
+    # the output layer's work is its kernel: its scope may sit on the
+    # custom call alone
+    output_layer = fusions + [ln for ln in hlo.splitlines() if KERNEL in ln]
     for scope in ("softmax_ce", "mixing", "update", "grad", "bottleneck",
                   "blstm_l0", "blstm_l5"):
+        lines = output_layer if scope == "softmax_ce" else fusions
         # a transform wraps the scope it runs in: vmap(jvp(softmax_ce))
         assert any(re.search(rf'op_name="[^"]*[/(]{scope}[)/]', ln)
-                   for ln in fusions), scope
+                   for ln in lines), scope
+    start = hlo.index("\nENTRY ")
+    entry = hlo[start:hlo.index("\n}\n", start)]
+    dw = 4 * 256 * 32000
+    logits = {(dt, dims) for dt, dims in re.findall(
+        r"\b(bf16|f32)\[([\d,]+)\]", entry)
+        if dims.endswith(",32000")
+        and math.prod(int(d) for d in dims.split(",")) > dw}
+    assert not logits, logits
