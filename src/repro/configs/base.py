@@ -74,16 +74,22 @@ class ArchConfig:
     # is active; e.g. hymba keeps first/middle/last global.
     global_attn_layers: tuple = ()
 
-    # encoder-decoder (whisper): number of encoder layers; seq_len of a
-    # shape is split evenly between encoder frames and decoder tokens.
+    # encoder-decoder (whisper): encoder layers; log-mel bins into the
+    # conv front end; encoder positions (after the front end's stride 2,
+    # so 2 x n_audio_ctx mel frames: 30 s at a 10 ms hop); learned decoder
+    # positions.  The encoder always takes its whole window; a shape's
+    # seq_len is the decoder's tokens, at most n_text_ctx.
     n_enc_layers: int = 0
+    n_mels: int = 0
+    n_audio_ctx: int = 0
+    n_text_ctx: int = 0
 
     # vlm: number of prefix patch-embedding positions for a given seq_len is
     # seq_len // vlm_patch_fraction_denom.
     vlm_patch_frac: float = 0.25
 
-    # modality frontend stub: 'none' | 'audio' (frame embeddings) |
-    # 'vision' (patch embeddings).
+    # modality frontend stub: 'none' | 'vision' (precomputed patch
+    # embeddings); whisper's audio front end is a layer of the model.
     frontend: str = "none"
 
     # lstm acoustic model (the paper's own architecture)
@@ -244,7 +250,9 @@ class ArchConfig:
                 chunk=16,
             )
         if self.n_enc_layers:
-            changes["n_enc_layers"] = 1
+            changes.update(n_enc_layers=1, n_mels=min(self.n_mels, 16),
+                           n_audio_ctx=min(self.n_audio_ctx, 32),
+                           n_text_ctx=min(self.n_text_ctx, 64))
         if self.lstm_hidden:
             changes["lstm_hidden"] = 64
             changes["lstm_bottleneck"] = 32

@@ -181,11 +181,13 @@ class SyntheticLMDataset:
 
 @dataclass
 class SyntheticSeq2SeqDataset:
-    """Frame embeddings -> token transcripts (whisper-style backbone)."""
+    """Log-mel frames -> token transcripts (whisper): ``mel`` (B, frames,
+    n_mels), teacher-forced ``tokens`` and next-token ``labels``
+    (B, dec_len), dec_len <= frames."""
 
-    d_model: int
+    n_mels: int
     vocab: int
-    enc_len: int
+    frames: int
     dec_len: int
     batch: int
     seed: int = 0
@@ -194,22 +196,21 @@ class SyntheticSeq2SeqDataset:
     def __post_init__(self):
         r = np.random.default_rng(self.seed)
         k = min(self.effective_vocab, self.vocab)
-        self.readout = r.normal(size=(self.d_model, k)).astype(np.float32)
+        self.readout = r.normal(size=(self.n_mels, k)).astype(np.float32)
         self.k = k
 
     def batch_at(self, step: int):
         r = _rng(self.seed, step)
-        frames = r.normal(size=(self.batch, self.enc_len,
-                                self.d_model)).astype(np.float32)
+        mel = r.normal(size=(self.batch, self.frames,
+                             self.n_mels)).astype(np.float32)
         # pooled frame windows determine target tokens (learnable alignment)
-        pool = self.enc_len // self.dec_len if self.enc_len >= self.dec_len else 1
-        trimmed = frames[:, :pool * self.dec_len].reshape(
-            self.batch, self.dec_len, pool, self.d_model).mean(2)
-        scores = trimmed @ self.readout
-        labels = scores.argmax(-1).astype(np.int32)
+        pool = self.frames // self.dec_len
+        pooled = mel[:, :pool * self.dec_len].reshape(
+            self.batch, self.dec_len, pool, self.n_mels).mean(2)
+        labels = (pooled @ self.readout).argmax(-1).astype(np.int32)
         tokens = np.concatenate(
             [np.zeros((self.batch, 1), np.int32), labels[:, :-1]], axis=1)
-        return {"frames": frames, "tokens": tokens, "labels": labels}
+        return {"mel": mel, "tokens": tokens, "labels": labels}
 
 
 @dataclass
@@ -251,9 +252,10 @@ def make_dataset(cfg, *, seq_len: int, batch: int, seed: int = 0,
                                    seed=seed, var_len=var_len or bucket,
                                    bucket=bucket)
     if fam == "encdec":
-        half = seq_len // 2
-        return SyntheticSeq2SeqDataset(cfg.d_model, cfg.vocab, half, half,
-                                       batch, seed=seed)
+        return SyntheticSeq2SeqDataset(cfg.n_mels, cfg.vocab,
+                                       2 * cfg.n_audio_ctx,
+                                       min(seq_len, cfg.n_text_ctx), batch,
+                                       seed=seed)
     if fam == "vlm":
         sp = int(seq_len * cfg.vlm_patch_frac)
         return SyntheticVLMDataset(cfg.d_model, cfg.vocab, sp, seq_len - sp,

@@ -62,7 +62,7 @@ class Model:
                    long_context: bool = False, kernel_impl: str = "jax"):
         fam = self.cfg.family
         if fam == "encdec":
-            return ED.prefill(self.cfg, params, batch["frames"],
+            return ED.prefill(self.cfg, params, batch["mel"],
                               batch["tokens"], cache_len=cache_len)
         patches = batch.get("patches")
         return TF.prefill(self.cfg, params, batch["tokens"],
@@ -88,8 +88,8 @@ class Model:
         cfg = self.cfg
         B = shape.global_batch
         if cfg.family == "encdec":
-            half = shape.seq_len // 2
-            return ED.cache_specs(cfg, B, half, half)
+            return ED.cache_specs(cfg, B, min(shape.seq_len, cfg.n_text_ctx),
+                                  cfg.n_audio_ctx)
         return TF.cache_specs(cfg, B, shape.seq_len)
 
     def page_specs(self, n_pages: int, page_size: int):
@@ -116,20 +116,16 @@ class Model:
             }
 
         if fam == "encdec":
-            half = S // 2
-            if mode == "train":
-                return {
-                    "frames": _emb((B, half, cfg.d_model),
-                                   ("batch", "frames", "embed")),
-                    "tokens": _i32((B, half), ("batch", "seq")),
-                    "labels": _i32((B, half), ("batch", "seq")),
-                }
-            if mode == "prefill":
-                return {
-                    "frames": _emb((B, half, cfg.d_model),
-                                   ("batch", "frames", "embed")),
-                    "tokens": _i32((B, half), ("batch", "seq")),
-                }
+            # the encoder's whole window of log-mel frames (30 s for
+            # whisper); seq_len tokens for the decoder, at most n_text_ctx
+            if mode in ("train", "prefill"):
+                T = min(S, cfg.n_text_ctx)
+                d = {"mel": _emb((B, 2 * cfg.n_audio_ctx, cfg.n_mels),
+                                 ("batch", "frames", "feature")),
+                     "tokens": _i32((B, T), ("batch", "seq"))}
+                if mode == "train":
+                    d["labels"] = _i32((B, T), ("batch", "seq"))
+                return d
             return {"tokens": _i32((B, 1), ("batch", None)),
                     "pos": _i32((), ())}
 

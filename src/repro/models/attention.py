@@ -22,6 +22,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.models.common import apply_rope
 from repro.sharding import ParamSpec
 
@@ -76,8 +77,8 @@ def attn_param_specs(cfg, *, dtype=None):
         "wo": ParamSpec((H, E, d), dt, ("heads", "head_dim", "embed"), "lecun"),
     }
     if cfg.use_bias:
+        # whisper's projections: q, v and out carry a bias, k none
         p["bq"] = ParamSpec((H, E), "float32", ("heads", "head_dim"), "zeros")
-        p["bk"] = ParamSpec((KV, E), "float32", ("kv_heads", "head_dim"), "zeros")
         p["bv"] = ParamSpec((KV, E), "float32", ("kv_heads", "head_dim"), "zeros")
         p["bo"] = ParamSpec((d,), "float32", ("embed",), "zeros")
     return p
@@ -89,7 +90,6 @@ def qkv_project(cfg, p, xq, xkv, positions_q=None, positions_kv=None):
     v = jnp.einsum("bsd,dhe->bshe", xkv, p["wv"])
     if "bq" in p:
         q = (q.astype(jnp.float32) + p["bq"]).astype(q.dtype)
-        k = (k.astype(jnp.float32) + p["bk"]).astype(k.dtype)
         v = (v.astype(jnp.float32) + p["bv"]).astype(v.dtype)
     if positions_q is not None:
         q = apply_rope(q, positions_q, cfg.rope_theta)
@@ -115,6 +115,12 @@ def attn_seq(q, k, v, *, causal: bool, window=None, q_chunk: int = 512,
     """q: (B,Sq,H,E), k/v: (B,Sk,KV,E).  window: scalar (traced ok); a
     window >= Sk is full attention.  Returns (B,Sq,H,E).
 
+    The queries run in chunks of ``q_chunk`` positions; when Sq is not a
+    multiple of it, the last chunk is shorter (whisper's 1500-frame
+    encoder: two chunks of 512 and one of 476).  Each trace counts once
+    in the ``repro.obs`` counter ``models/attn_seq``, tagged ``ragged``
+    (a shorter last chunk) and ``causal``.
+
     seq_shard=True is the sequence-parallel mode (EXPERIMENTS.md §Perf):
     K/V are gathered to full head_dim (cheap: one (B,Sk,KV,E) gather per
     layer) and each q chunk's position dim is sharded over the 'model'
@@ -125,7 +131,6 @@ def attn_seq(q, k, v, *, causal: bool, window=None, q_chunk: int = 512,
     G, M = KV, H // KV
     scale = 1.0 / np.sqrt(E)
     k_pos = jnp.arange(Sk)
-    from jax.sharding import PartitionSpec as P
 
     if stub:
         # attention-ablated stand-in (o = q): zero score traffic/compute.
@@ -134,97 +139,70 @@ def attn_seq(q, k, v, *, causal: bool, window=None, q_chunk: int = 512,
         # projected roofline (never a model path).
         return q
 
-    if seq_shard and seq_shard_chunked:
-        # forward-only paths (prefill): q-chunk scan ON TOP of the sequence
-        # sharding bounds the materialized score block to
-        # (q_chunk/16, Sk) per device — the per-chunk reshard is cheap when
-        # there is no backward pass to mirror it (§Perf pair-B iter 3).
-        k, v = _gather_last(k), _gather_last(v)
-        qg = q.reshape(B, Sq, G, M, E)
-        q_chunk = min(q_chunk, Sq)
-        n_chunks = Sq // q_chunk
-        scale_ = 1.0 / np.sqrt(E)
-
-        def one_chunk(i):
-            qs = jax.lax.dynamic_slice_in_dim(qg, i * q_chunk, q_chunk, 1)
-            asg = {1: "model", 4: None}
-            if batch_axis:
-                asg[0] = batch_axis
-            qs = _constrain_dims(qs, asg)
-            s = jnp.einsum("bcgme,btge->bgmct", qs, k) * scale_
-            s = s.astype(jnp.float32)
-            if causal:
-                q_pos = pos_offset + i * q_chunk + jnp.arange(q_chunk)
-                ok = q_pos[:, None] >= k_pos[None, :]
-                if window is not None:
-                    ok = ok & (q_pos[:, None] - k_pos[None, :] < window)
-                s = jnp.where(ok[None, None, None], s, NEG_INF)
-            p = jax.nn.softmax(s, axis=-1).astype(v.dtype)
-            return jnp.einsum("bgmct,btge->bcgme", p, v)
-
-        if n_chunks == 1:
-            o = one_chunk(jnp.int32(0))
-        else:
-            _, os_ = jax.lax.scan(lambda c, i: (c, one_chunk(i)), 0,
-                                  jnp.arange(n_chunks))
-            o = jnp.moveaxis(os_, 0, 1).reshape(B, Sq, G, M, E)
-        return o.reshape(B, Sq, G * M, E)
-
+    asg = {1: "model", 4: None}
+    if batch_axis:
+        asg[0] = batch_axis
     if seq_shard:
+        k, v = _gather_last(k), _gather_last(v)
+
+    def mask(s, q_pos):
+        if not causal:
+            return s
+        ok = q_pos[:, None] >= k_pos[None, :]
+        if window is not None:
+            ok = ok & (q_pos[:, None] - k_pos[None, :] < window)
+        return jnp.where(ok[None, None, None], s, NEG_INF)
+
+    if seq_shard and not seq_shard_chunked:
         # Megatron-SP-style: ONE reshard per layer — gather K/V to full
         # head_dim, shard q's position dim over 'model'.  No chunk scan:
         # the per-device score block is already 1/model_size of (Sq, Sk).
         # (§Perf iter 5 — REFUTED: a hand-rolled bf16-materialized softmax
         # added more fusion boundaries than it saved; f32 softmax fuses
         # better. Kept the single-reshard structure from iter 2.)
-        k, v = _gather_last(k), _gather_last(v)
-        asg = {1: "model", 4: None}
-        if batch_axis:
-            asg[0] = batch_axis
         qg = _constrain_dims(q.reshape(B, Sq, G, M, E), asg)
         # (§Perf iter 6 — REFUTED: a q-major 'bsgmt' layout was tried to
         # remove a transpose+copy of the scores; it measured 5% WORSE —
         # the partitioner preferred the head-major layout.)
         s = jnp.einsum("bsgme,btge->bgmst", qg, k) * scale
-        s = s.astype(jnp.float32)
-        if causal:
-            q_pos = pos_offset + jnp.arange(Sq)
-            ok = q_pos[:, None] >= k_pos[None, :]
-            if window is not None:
-                ok = ok & (q_pos[:, None] - k_pos[None, :] < window)
-            s = jnp.where(ok[None, None, None], s, NEG_INF)
+        s = mask(s.astype(jnp.float32), pos_offset + jnp.arange(Sq))
         p = jax.nn.softmax(s, axis=-1).astype(v.dtype)
         o = jnp.einsum("bgmst,btge->bsgme", p, v)
         return o.reshape(B, Sq, G * M, E)
 
     qg = q.reshape(B, Sq, G, M, E)
     q_chunk = min(q_chunk, Sq)
-    n_chunks = Sq // q_chunk
-    assert n_chunks * q_chunk == Sq, (Sq, q_chunk)
+    n_full, tail = divmod(Sq, q_chunk)
+    obs.counter("models/attn_seq", ragged=int(tail > 0),
+                causal=int(causal)).inc()
 
-    @jax.checkpoint
-    def one_chunk(i):
-        qs = jax.lax.dynamic_slice_in_dim(qg, i * q_chunk, q_chunk, 1)
+    def one_chunk(start, size):
+        qs = jax.lax.dynamic_slice_in_dim(qg, start, size, 1)
+        if seq_shard:
+            # forward-only paths (prefill): the q-chunk scan ON TOP of the
+            # sequence sharding bounds the materialized score block to
+            # (q_chunk/16, Sk) per device — the per-chunk reshard is cheap
+            # when there is no backward pass to mirror it (§Perf pair-B
+            # iter 3).
+            qs = _constrain_dims(qs, asg)
         s = jnp.einsum("bcgme,btge->bgmct", qs, k) * scale
-        s = s.astype(jnp.float32)
-        if causal:
-            q_pos = pos_offset + i * q_chunk + jnp.arange(q_chunk)
-            ok = q_pos[:, None] >= k_pos[None, :]
-            if window is not None:
-                ok = ok & (q_pos[:, None] - k_pos[None, :] < window)
-            s = jnp.where(ok[None, None, None], s, NEG_INF)
+        s = mask(s.astype(jnp.float32), pos_offset + start + jnp.arange(size))
         p = jax.nn.softmax(s, axis=-1).astype(v.dtype)
         return jnp.einsum("bgmct,btge->bcgme", p, v)
 
-    if n_chunks == 1:
-        o = one_chunk(jnp.int32(0))
+    if not seq_shard:
+        # training: each chunk's scores are recomputed in the backward
+        one_chunk = jax.checkpoint(one_chunk, static_argnums=(1,))
+    if n_full == 1:
+        parts = [one_chunk(0, q_chunk)]
     else:
         _, os_ = jax.lax.scan(
-            lambda c, i: (c, one_chunk(i)), 0, jnp.arange(n_chunks)
-        )
-        o = jnp.moveaxis(os_, 0, 1).reshape(B, n_chunks * q_chunk, G, M, E)
-        o = o.reshape(B, Sq, G * M, E)
-        return o
+            lambda c, i: (c, one_chunk(i * q_chunk, q_chunk)), 0,
+            jnp.arange(n_full))
+        parts = [jnp.moveaxis(os_, 0, 1).reshape(B, n_full * q_chunk, G, M, E)]
+    if tail:
+        parts.append(one_chunk(n_full * q_chunk, tail))
+    o = jnp.concatenate(parts, axis=1) if tail else parts[0]
     return o.reshape(B, Sq, G * M, E)
 
 

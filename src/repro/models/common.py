@@ -133,4 +133,6 @@ def dense(p, x):
 
 
 def gelu(x):
-    return jax.nn.gelu(x, approximate=True)
+    """The exact GELU, x * Phi(x) through erf (``act="gelu"``, as
+    whisper's published config names it), not the tanh approximation."""
+    return jax.nn.gelu(x, approximate=False)

@@ -32,6 +32,17 @@ def test_train_cli(tmp_path):
     assert any(d.startswith("step_") for d in os.listdir(tmp_path / "ck"))
 
 
+def test_train_cli_whisper():
+    """whisper-large-v3 trains through the same CLI, log-mel segments into
+    its conv front end, at the CLI's reduced sizes."""
+    r = run(["repro.launch.train", "--arch", "whisper-large-v3", "--reduced",
+             "--learners", "2", "--strategy", "ad_psgd", "--steps", "3",
+             "--log-every", "1"])
+    assert r.returncode == 0, r.stderr[-2000:]
+    assert "done: 3 steps in" in r.stdout and "[ad_psgd, L=2]" in r.stdout
+    assert "final loss nan" not in r.stdout
+
+
 def test_serve_cli():
     r = run(["repro.launch.serve", "--arch", "smollm-360m", "--requests",
              "2", "--slots", "1", "--max-new", "4", "--prompt-len", "8",

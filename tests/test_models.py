@@ -108,14 +108,14 @@ def test_prefill_decode_consistency(zoo, name):
 def test_prefill_decode_consistency_encdec(zoo):
     cfg, model, params = zoo["whisper-large-v3"]
     T = 16
-    frames = jax.random.normal(jax.random.PRNGKey(4), (B, T, cfg.d_model),
-                               jnp.float32).astype(jnp.bfloat16)
+    mel = jax.random.normal(jax.random.PRNGKey(4),
+                            (B, 2 * cfg.n_audio_ctx, cfg.n_mels), jnp.float32)
     toks = jax.random.randint(jax.random.PRNGKey(5), (B, T + 1), 0,
                               cfg.vocab, jnp.int32)
     full, _ = model.prefill_fn(
-        params, {"frames": frames, "tokens": toks}, cache_len=T + 1)
+        params, {"mel": mel, "tokens": toks}, cache_len=T + 1)
     part, cache = model.prefill_fn(
-        params, {"frames": frames, "tokens": toks[:, :T]}, cache_len=T + 1)
+        params, {"mel": mel, "tokens": toks[:, :T]}, cache_len=T + 1)
     lg, _ = model.decode_fn(params, cache, toks[:, T:T + 1], jnp.int32(T))
     np.testing.assert_allclose(np.asarray(lg[:, 0], np.float32),
                                np.asarray(full[:, -1], np.float32),
@@ -194,6 +194,106 @@ def test_window_masks_attention():
     expect = attention_ref(q, k, v, causal=True, window=16)
     np.testing.assert_allclose(np.asarray(out), np.asarray(expect),
                                atol=1e-4, rtol=1e-4)
+
+
+@pytest.mark.parametrize("causal", [False, True])
+def test_attn_seq_takes_a_shorter_last_chunk(causal):
+    """75 positions in chunks of 32 leave a last chunk of 11, as whisper's
+    1500-frame encoder leaves one of 476 after two of 512: the output and
+    its gradients equal the unchunked softmax attention's (float32, so to
+    float32 rounding)."""
+    from repro.kernels.ref import attention_ref
+    from repro.models.attention import attn_seq
+
+    q = jax.random.normal(jax.random.PRNGKey(1), (2, 75, 4, 16))
+    k = jax.random.normal(jax.random.PRNGKey(2), (2, 75, 2, 16))
+    v = jax.random.normal(jax.random.PRNGKey(3), (2, 75, 2, 16))
+    out, grads = jax.value_and_grad(
+        lambda *a: jnp.sum(jnp.sin(attn_seq(*a, causal=causal, q_chunk=32))),
+        argnums=(0, 1, 2))(q, k, v)
+    want, want_g = jax.value_and_grad(
+        lambda *a: jnp.sum(jnp.sin(attention_ref(*a, causal=causal))),
+        argnums=(0, 1, 2))(q, k, v)
+    np.testing.assert_allclose(float(out), float(want), rtol=1e-5)
+    for g, w in zip(grads, want_g):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w), atol=1e-5,
+                                   rtol=1e-4)
+
+
+def test_gelu_is_the_exact_erf_form():
+    """``act="gelu"`` (whisper's FFN and front end) is x * Phi(x) through
+    erf, to float32 rounding, not the tanh approximation, which is up to
+    5e-4 away on this range."""
+    from jax.scipy.special import erf
+
+    from repro.models.common import gelu
+
+    x = jnp.linspace(-6.0, 6.0, 4001, dtype=jnp.float32)
+    exact = 0.5 * x * (1.0 + erf(x / np.sqrt(2.0)))
+    np.testing.assert_allclose(np.asarray(gelu(x)), np.asarray(exact),
+                               atol=2e-6)
+    assert float(jnp.max(jnp.abs(jax.nn.gelu(x, approximate=True)
+                                 - exact))) > 1e-4
+
+
+def _tiny_whisper():
+    """2 + 2 layers at d_model 64 with 520 encoder positions: the query
+    chunks of 512 leave a last one of 8."""
+    cfg = dataclasses.replace(
+        get_arch("whisper-large-v3").reduced(), n_layers=2, n_enc_layers=2,
+        d_model=64, n_heads=4, n_kv_heads=4, head_dim=16, d_ff=128,
+        vocab=96, n_mels=8, n_audio_ctx=520, n_text_ctx=16)
+    model = build_model(cfg)
+    params = init_spec_tree(model.param_specs(), RNG)
+    batch = {"mel": jax.random.normal(RNG, (B, 1040, 8), jnp.float32),
+             "tokens": jnp.zeros((B, 12), jnp.int32),
+             "labels": jnp.ones((B, 12), jnp.int32)}
+    return model, params, batch
+
+
+def test_whisper_conv_front_end_matches_lax_conv():
+    """The front end's convolutions (kernel 3, padding 1; stride 1, then
+    2) equal lax.conv_general_dilated in float32."""
+    from repro.models.encdec import conv1d
+
+    x = jax.random.normal(jax.random.PRNGKey(1), (2, 41, 8))
+    for stride, c_in in ((1, 8), (2, 8)):
+        p = {"w": jax.random.normal(jax.random.PRNGKey(stride), (3, c_in, 6)),
+             "b": jax.random.normal(jax.random.PRNGKey(9), (6,))}
+        got = conv1d(p, x, stride)
+        want = jax.lax.conv_general_dilated(
+            x, p["w"], (stride,), [(1, 1)],
+            dimension_numbers=("NWC", "WIO", "NWC"),
+            precision=jax.lax.Precision.HIGHEST) + p["b"]
+        assert got.shape == (2, (41 - 1) // stride + 1, 6)
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   atol=1e-5, rtol=1e-5)
+
+
+def test_whisper_step_names_its_layers_and_counts_attention():
+    """The compiled training step carries the scopes frontend, encoder,
+    decoder, self_attn, cross_attn and out_head in its op metadata, and
+    the ``models/attn_seq`` counter counts one trace of each attention:
+    the encoder's with a shorter last chunk, the decoder's causal self-
+    and plain cross-attention."""
+    import re
+
+    from repro import obs
+
+    model, params, batch = _tiny_whisper()
+    obs.configure()
+    try:
+        hlo = jax.jit(jax.value_and_grad(model.loss_fn)).lower(
+            params, batch).compile().as_text()
+        counts = {(r["tags"]["ragged"], r["tags"]["causal"]): r["value"]
+                  for r in obs.get_metrics().snapshot()
+                  if r["name"] == "models/attn_seq"}
+    finally:
+        obs.reset()
+    assert counts == {(1, 0): 1, (0, 1): 1, (0, 0): 1}
+    for scope in ("frontend", "encoder", "decoder", "self_attn",
+                  "cross_attn", "out_head"):
+        assert re.search(rf'op_name="[^"]*[/(]{scope}[)/]', hlo), scope
 
 
 def test_layer_windows_global_override():
