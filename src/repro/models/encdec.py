@@ -13,12 +13,20 @@ positions (:func:`_embed`).
 The parts run under ``jax.named_scope`` ``frontend``, ``encoder``,
 ``decoder``, ``self_attn``, ``cross_attn`` and ``out_head``, which land
 in the compiled ops' metadata.
+
+Under ``cfg.remat`` each encoder and decoder layer is rematerialised
+with :data:`LAYER_POLICY`: the backward keeps each attention's q/k/v
+projections, its output and its output projection, and recomputes the
+LayerNorms, residual adds and the FFN, and (inside ``attn_seq``'s own
+per-chunk checkpoint) the attention scores.
 """
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
+from repro import obs
 from repro.models import attention as A
 from repro.models import ffn as F
 from repro.models.common import (apply_norm, cross_entropy, gelu, norm_spec,
@@ -27,6 +35,13 @@ from repro.models.transformer import _pad_cache, _stack
 from repro.sharding import ParamSpec
 
 CONV_K = 3      # both front-end convolutions: kernel 3, padding 1
+
+# what a layer's backward keeps: each attention's q/k/v projections, its
+# output and its output projection.  The FFN's (B, S, d_ff) hidden would
+# take as much again, past what one chip holds beside four learners.
+ATTN_QKV, ATTN_OUT, ATTN_PROJ = "attn_qkv", "attn_out", "attn_proj"
+LAYER_POLICY = jax.checkpoint_policies.save_only_these_names(
+    ATTN_QKV, ATTN_OUT, ATTN_PROJ)
 
 
 def enc_layer_specs(cfg):
@@ -97,25 +112,47 @@ def frontend(params, mel):
         return x + sinusoidal_positions(jnp.arange(S), d).astype(x.dtype)
 
 
+def _attention(cfg, p, xq, xkv, **kw):
+    """Projections, ``attn_seq`` and the output projection, each output
+    named for :data:`LAYER_POLICY`; returns the output and (k, v)."""
+    q, k, v = (checkpoint_name(t, ATTN_QKV)
+               for t in A.qkv_project(cfg, p, xq, xkv))
+    o = checkpoint_name(A.attn_seq(q, k, v, **kw), ATTN_OUT)
+    return checkpoint_name(A.out_project(p, o), ATTN_PROJ), (k, v)
+
+
+def _remat(cfg, layer, stack: str):
+    """The layer body a stack scans: under ``cfg.remat`` checkpointed
+    with :data:`LAYER_POLICY` (``prevent_cse=False``: the scan already
+    keeps the recompute apart), else as it is.  Each trace counts once
+    in the ``repro.obs`` counter ``models/remat``, tagged ``stack``
+    (``enc``/``dec``) and ``policy`` (``dots+attn_out``/``none``)."""
+    obs.counter("models/remat", stack=stack,
+                policy="dots+attn_out" if cfg.remat else "none").inc()
+    if not cfg.remat:
+        return layer
+    return jax.checkpoint(layer, policy=LAYER_POLICY, prevent_cse=False)
+
+
 def encode(cfg, params, mel, *, batch_axis="", fwd_only=False):
     """mel: (B, 2 * S_enc, n_mels) log-mel frames -> (B, S_enc, d)."""
     x = frontend(params, mel)
     seq_shard = cfg.attn_sharding == "seq"
 
-    @jax.checkpoint
     def layer(x, p):
         h = apply_norm(p["ln1"], x)
         with jax.named_scope("self_attn"):
-            q, k, v = A.qkv_project(cfg, p["attn"], h, h)
-            o = A.attn_seq(q, k, v, causal=False, seq_shard=seq_shard,
-                           seq_shard_chunked=seq_shard and fwd_only,
-                           batch_axis=batch_axis)
-            x = x + A.out_project(p["attn"], o)
+            y, _ = _attention(cfg, p["attn"], h, h, causal=False,
+                              seq_shard=seq_shard,
+                              seq_shard_chunked=seq_shard and fwd_only,
+                              batch_axis=batch_axis)
+            x = x + y
         x = x + F.ffn_apply(cfg, p["mlp"], apply_norm(p["ln2"], x))
         return x.astype(jnp.bfloat16), None
 
     with jax.named_scope("encoder"):
-        x, _ = jax.lax.scan(layer, x, params["enc_layers"])
+        x, _ = jax.lax.scan(_remat(cfg, layer, "enc"), x,
+                            params["enc_layers"])
         return apply_norm(params["enc_norm"], x)
 
 
@@ -136,23 +173,25 @@ def decode_seq(cfg, params, tokens, enc_out, *, collect_cache=False,
     def layer(x, p):
         h = apply_norm(p["ln1"], x)
         with jax.named_scope("self_attn"):
-            q, k, v = A.qkv_project(cfg, p["self_attn"], h, h)
-            o = A.attn_seq(q, k, v, causal=True, seq_shard=seq_shard,
-                           seq_shard_chunked=chunked, batch_axis=batch_axis)
-            x = x + A.out_project(p["self_attn"], o)
+            y, (k, v) = _attention(cfg, p["self_attn"], h, h, causal=True,
+                                   seq_shard=seq_shard,
+                                   seq_shard_chunked=chunked,
+                                   batch_axis=batch_axis)
+            x = x + y
         h = apply_norm(p["lnx"], x)
         with jax.named_scope("cross_attn"):
-            q, ck, cv = A.qkv_project(cfg, p["cross_attn"], h, enc_out)
-            o = A.attn_seq(q, ck, cv, causal=False, seq_shard=seq_shard,
-                           seq_shard_chunked=chunked, batch_axis=batch_axis)
-            x = x + A.out_project(p["cross_attn"], o)
+            y, (ck, cv) = _attention(cfg, p["cross_attn"], h, enc_out,
+                                     causal=False, seq_shard=seq_shard,
+                                     seq_shard_chunked=chunked,
+                                     batch_axis=batch_axis)
+            x = x + y
         x = x + F.ffn_apply(cfg, p["mlp"], apply_norm(p["ln2"], x))
         cache = (_pad_cache(k, v, cache_len), (ck, cv)) if collect_cache else ()
         return x.astype(jnp.bfloat16), cache
 
-    body = jax.checkpoint(layer) if cfg.remat else layer
     with jax.named_scope("decoder"):
-        x, caches = jax.lax.scan(body, x, params["dec_layers"])
+        x, caches = jax.lax.scan(_remat(cfg, layer, "dec"), x,
+                                 params["dec_layers"])
         return apply_norm(params["dec_norm"], x), caches
 
 

@@ -296,6 +296,111 @@ def test_whisper_step_names_its_layers_and_counts_attention():
         assert re.search(rf'op_name="[^"]*[/(]{scope}[)/]', hlo), scope
 
 
+def _whisper_grads(model, params, batch, *, remat=True, policy=None):
+    """The tiny whisper's loss and gradients, its layers rematerialised
+    with ``policy`` in place of ``encdec.LAYER_POLICY``, or not at all."""
+    from repro.models import encdec
+
+    saved = encdec.LAYER_POLICY
+    encdec.LAYER_POLICY = policy or saved
+    try:
+        m = build_model(dataclasses.replace(model.cfg, remat=remat))
+        return jax.jit(jax.value_and_grad(m.loss_fn))(params, batch)
+    finally:
+        encdec.LAYER_POLICY = saved
+
+
+@pytest.mark.parametrize("want,got,rel", [
+    ("nothing_saveable", "policy", 0.0),
+    ("no_remat", "policy", 3e-2),
+    ("no_remat", "nothing_saveable", 3e-2)])
+def test_whisper_remat_policy_keeps_loss_and_gradients(want, got, rel):
+    """The layer policy (keep the projections and attention outputs)
+    gives the loss and every gradient leaf of a plain checkpoint that
+    keeps nothing, to float32 rounding.  Without any checkpoint the loss
+    is the same and the gradients differ by the bfloat16 rounding of the
+    backward's sums, whose order the checkpoint changes: 1.1e-2 of a
+    leaf's norm at most here, and as large for the plain checkpoint as
+    for the policy."""
+    model, params, batch = _tiny_whisper()
+    runs = {
+        "policy": lambda: _whisper_grads(model, params, batch),
+        "nothing_saveable": lambda: _whisper_grads(
+            model, params, batch,
+            policy=jax.checkpoint_policies.nothing_saveable),
+        "no_remat": lambda: _whisper_grads(model, params, batch,
+                                           remat=False)}
+    (lw, gw), (lg, gg) = runs[want](), runs[got]()
+    np.testing.assert_allclose(float(lg), float(lw), rtol=1e-6)
+    for a, b in zip(jax.tree.leaves(gw), jax.tree.leaves(gg)):
+        a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
+        if rel:
+            assert np.linalg.norm(b - a) <= rel * np.linalg.norm(a)
+        else:
+            np.testing.assert_allclose(b, a, rtol=1e-6, atol=1e-7)
+
+
+def test_whisper_remat_saves_projections_and_outputs_not_scores():
+    """What the layer policy keeps for the backward (2 encoder layers, 3
+    decoder layers, so the stacks tell apart): no attention score block
+    of any query chunk; the attention outputs (one per encoder layer,
+    two per decoder layer: the counts drop by that much when the policy
+    leaves ``attn_out`` out) and the q/k/v projections, among them the
+    cross-attention's k and v over the encoder output."""
+    import collections
+
+    from jax._src.ad_checkpoint import saved_residuals
+
+    from repro.models import encdec
+
+    model, _, batch = _tiny_whisper()
+    model = build_model(dataclasses.replace(model.cfg, n_layers=3))
+    params = init_spec_tree(model.param_specs(), RNG)
+    names = jax.checkpoint_policies.save_only_these_names
+
+    def residuals(policy):
+        saved = encdec.LAYER_POLICY
+        encdec.LAYER_POLICY = policy
+        try:
+            res = saved_residuals(model.loss_fn, params, batch)
+        finally:
+            encdec.LAYER_POLICY = saved
+        return collections.Counter(tuple(a.shape) for a, _ in res)
+
+    got = residuals(encdec.LAYER_POLICY)
+    # scores (B, heads, 1, q_chunk, S_k): encoder chunks of 512 and 8 over
+    # 520 keys, decoder 12 queries over 12 and 520 keys
+    scores = {(512, 520), (8, 520), (12, 12), (12, 520)}
+    assert not [s for s in got if s[-2:] in scores]
+    enc, dec, cross = (2, B, 520, 4, 16), (3, B, 12, 4, 16), (3, B, 520, 4, 16)
+    no_out = residuals(names(encdec.ATTN_QKV, encdec.ATTN_PROJ))
+    assert (got[enc] - no_out[enc], got[dec] - no_out[dec]) == (1, 2)
+    no_qkv = residuals(names(encdec.ATTN_OUT, encdec.ATTN_PROJ))
+    assert got[enc] - no_qkv[enc] >= 3        # q, k, v
+    assert got[dec] - no_qkv[dec] >= 4        # self q, k, v; cross q
+    assert got[cross] - no_qkv[cross] == 2    # cross k, v
+
+
+@pytest.mark.parametrize("remat,policy", [(True, "dots+attn_out"),
+                                          (False, "none")])
+def test_whisper_remat_counter_counts_each_stack(remat, policy):
+    """The ``models/remat`` counter counts one trace of each stack, with
+    the policy the configuration asks for."""
+    from repro import obs
+
+    model, params, batch = _tiny_whisper()
+    model = build_model(dataclasses.replace(model.cfg, remat=remat))
+    obs.configure()
+    try:
+        jax.jit(jax.value_and_grad(model.loss_fn)).lower(params, batch)
+        counts = {(r["tags"]["stack"], r["tags"]["policy"]): r["value"]
+                  for r in obs.get_metrics().snapshot()
+                  if r["name"] == "models/remat"}
+    finally:
+        obs.reset()
+    assert counts == {("enc", policy): 1, ("dec", policy): 1}
+
+
 def test_layer_windows_global_override():
     from repro.models.transformer import layer_windows, GLOBAL_WINDOW
 
