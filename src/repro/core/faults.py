@@ -9,25 +9,18 @@ conditions: a :class:`FaultPlan` is a *pure function of its seed* that
 schedules every fault, so a run under a plan is exactly reproducible and
 two strategies compared under the same plan see the same cluster weather.
 
-The plan is consumed at two boundaries:
-
-* **The step loop** (``repro.core.strategies.make_elastic_train_step`` /
-  ``repro.launch.train --fault-*``): :meth:`FaultPlan.step_inputs` yields
-  per-step numpy masks — which learners are alive, which contribute a
-  gradient this step (stragglers/stalls), who rejoins, which gossip edges
-  deliver, whose payloads are corrupted — that are fed to the jitted
-  elastic step as plain arrays (constant shapes, one compile).
-* **The perfsim boundary** (``benchmarks/perfsim``): the same plan's
-  :meth:`speed_factors` / :meth:`stall_extra` / departure schedule drive
-  the discrete-event wall-clock simulator at pod-scale learner counts,
-  so convergence (real training) and throughput (simulated cluster) are
-  reported under ONE fault description.
+The plan is consumed by the step loop
+(``repro.core.strategies.make_train_step(..., fault_plan=plan)`` /
+``repro.launch.train --fault-*``): :meth:`FaultPlan.step_inputs` yields
+per-step numpy masks — which learners are alive, which contribute a
+gradient this step (stragglers/stalls), who rejoins, which gossip edges
+deliver, whose payloads are corrupted — that are fed to the jitted step
+as plain arrays (constant shapes, one compile).
 
 Faults modeled (all per-learner, all deterministic from ``seed``):
 
 * **stragglers** — heterogeneous speed: a learner with factor ``m``
-  computes a gradient only every ``m``-th step (step-loop view) / takes
-  ``m×`` the base per-batch time (perfsim view).
+  computes a gradient only every ``m``-th step.
 * **heavy-tailed stalls** — with ``stall_prob`` per step a learner
   freezes for a Pareto(``stall_shape``)-distributed number of steps
   (GC pauses, network hiccups, preemptions).
@@ -158,14 +151,6 @@ class FaultPlan:
         return out
 
     # -- stragglers / stalls --------------------------------------------
-    def speed_factors(self) -> np.ndarray:
-        """f64 (L,): per-learner slowdown multipliers (1.0 = nominal) —
-        the perfsim view of the straggler schedule."""
-        f = np.ones(self.n_learners)
-        for s in self.stragglers:
-            f[s.learner] = max(f[s.learner], float(s.factor))
-        return f
-
     def _straggler_contrib(self, step: int) -> np.ndarray:
         c = np.ones(self.n_learners, bool)
         for s in self.stragglers:
@@ -204,19 +189,6 @@ class FaultPlan:
             return np.zeros(self.n_learners, bool)
         return self._stall_bitmap(step)[:, step]
 
-    def stall_extra(self, learner: int, k: int) -> float:
-        """Extra stall time (in units of the base per-batch time) charged
-        to learner ``learner``'s ``k``-th batch — the perfsim view of the
-        same heavy-tailed stall process."""
-        if self.stall_prob <= 0:
-            return 0.0
-        r = np.random.default_rng((np.uint64(self.seed), np.uint64(learner),
-                                   np.uint64(k), np.uint64(13)))
-        if r.random() >= self.stall_prob:
-            return 0.0
-        return float(min(self.stall_max,
-                         np.ceil(r.pareto(self.stall_shape) + 1)))
-
     # -- gossip faults ---------------------------------------------------
     def edge_ok_at(self, step: int) -> np.ndarray:
         """f32 (L, L): 1 where the undirected mixing edge (i, j) delivers
@@ -245,7 +217,7 @@ class FaultPlan:
 
     # -- the step-loop contract -----------------------------------------
     def step_inputs(self, step: int) -> dict:
-        """Everything the elastic train step needs for one step, as
+        """Everything a step built with this plan needs for one step, as
         constant-shape numpy arrays (one jit compile for the whole run):
 
         ========== ========= =============================================
@@ -269,16 +241,6 @@ class FaultPlan:
             "edge_ok": self.edge_ok_at(step),
             "corrupt": self.corrupt_at(step),
         }
-
-    def no_fault_inputs(self) -> dict:
-        """The trivial (fault-free) step inputs — what a plan-less elastic
-        step sees."""
-        L = self.n_learners
-        ones = np.ones(L, np.float32)
-        return {"active": ones, "contrib": ones.copy(),
-                "rejoin": np.zeros(L, np.float32),
-                "edge_ok": np.ones((L, L), np.float32),
-                "corrupt": np.zeros(L, np.float32)}
 
     # -- (de)serialization ----------------------------------------------
     def to_dict(self) -> dict:

@@ -32,9 +32,9 @@ does not exist in a single SPMD program, so AD-PSGD's asynchrony is modeled
 previous iterate while the mixing of the current iterate proceeds in
 parallel.  This is exactly the communication/computation overlap the paper
 credits for AD-PSGD's speedup, and it preserves the algorithm's convergence
-analysis (staleness tau=1..tau_max).  Wall-clock effects (stragglers, load
-balancing, Table II/III) are studied with the discrete-event simulator in
-``benchmarks/perfsim.py``.
+analysis (staleness tau=1..tau_max).  Stragglers, crashes and dropped
+gossip enter through a ``FaultPlan`` given to :func:`make_train_step`
+(docs/fault_tolerance.md).
 
 Learner replicas are a stacked leading axis sharded over the mesh
 ('data' axis on one pod; 'pod' axis for hring), so each chip only ever
@@ -115,9 +115,9 @@ def check_active(active) -> int:
     """Host-side guard for the all-inactive-learner edge: frame-weighted
     aggregation over an empty learner set is 0/0, and the jitted step
     only *clamps* the denominator (traced values cannot raise).  Call
-    this on the step's activity mask before invoking the elastic step;
-    returns the live count.  ``repro.core.faults.FaultPlan`` applies the
-    same rule to every membership event at plan construction."""
+    this on the activity mask before each call of a step built with a
+    fault plan; returns the live count.  ``FaultPlan`` applies the same
+    rule to every membership event at plan construction."""
     n = int(np.asarray(active).sum())
     if n <= 0:
         raise ValueError(
@@ -216,15 +216,23 @@ def _data_parallel_grad(grad_one, shard):
                          out_specs=(P(), P()), check_vma=False)
 
 
-def consensus_distance(params):
+def consensus_distance(params, active=None):
     """Mean L2 distance of learner replicas from their average — the
-    consensus diagnostic for decentralized SGD (paper §IV-C)."""
+    consensus diagnostic for decentralized SGD (paper §IV-C).  Given the
+    (L,) ``active`` mask, over the live learners only (a crashed
+    learner's frozen replica is cluster weather, not disagreement)."""
     def one(w):
         if w.ndim == 0 or w.shape[0] == 1:
             return jnp.float32(0.0), jnp.float32(1.0)
         wf = w.astype(jnp.float32)
-        mu = jnp.mean(wf, axis=0, keepdims=True)
-        return jnp.sum(jnp.square(wf - mu)), jnp.float32(wf.size)
+        if active is None:
+            mu = jnp.mean(wf, axis=0, keepdims=True)
+            return jnp.sum(jnp.square(wf - mu)), jnp.float32(wf.size)
+        n_act = jnp.maximum(jnp.sum(active), 1.0)
+        a = active.reshape((-1,) + (1,) * (w.ndim - 1))
+        mu = jnp.sum(wf * a, axis=0, keepdims=True) / n_act
+        per = jnp.float32(wf.size) / wf.shape[0]
+        return jnp.sum(jnp.square(wf - mu) * a), n_act * per
 
     parts = [one(w) for w in jax.tree.leaves(params)]
     num = sum(p[0] for p in parts)
@@ -252,10 +260,6 @@ class Strategy:
     block_size: int = 0         # >0: BMUF block length (in steps)
     block_momentum: float = 0.9
     block_lr: float = 1.0
-
-    @property
-    def mixer(self) -> str:     # pre-substrate name, kept for callers
-        return self.topology
 
 
 STRATEGIES = {
@@ -305,12 +309,15 @@ def transport_from_cfg(cfg, strategy: Strategy) -> Transport:
 # ---------------------------------------------------------------------------
 
 def init_state(strategy: Strategy, params, optimizer: Optimizer,
-               transport: Optional[Transport] = None):
+               transport: Optional[Transport] = None, fault_plan=None):
     """params: already stacked with the learner dim if strategy.replicated.
 
-    Pass the SAME ``transport`` given to :func:`make_train_step`: wires
-    with error feedback (topk) carry their residuals in ``state['comm']``
-    (f32 regardless of the parameter dtype)."""
+    Pass the SAME ``transport`` and ``fault_plan`` given to
+    :func:`make_train_step`: wires with error feedback (topk) carry their
+    residuals in ``state['comm']`` (f32 regardless of the parameter
+    dtype), and a fault plan adds the per-learner ``staleness`` counters
+    (steps since the learner last contributed a gradient) that drive
+    staleness-aware mixing weights."""
     transport = transport if transport is not None \
         else default_transport(strategy)
     state = {
@@ -330,6 +337,8 @@ def init_state(strategy: Strategy, params, optimizer: Optimizer,
             lambda w: jnp.zeros(w.shape, jnp.float32), params)
     if strategy.replicated and transport.needs_state:
         state["comm"] = transport.init_comm(params)
+    if fault_plan is not None:
+        state["staleness"] = jnp.zeros((_learner_dim(params),), jnp.int32)
     return state
 
 
@@ -352,12 +361,81 @@ def _grad_norm_stacked(g_l):
     return jnp.sqrt(sq)
 
 
+# Each mask-dependent helper below is the identity (or the plain
+# function) when its mask is None, so a step built without a fault plan
+# traces none of their ops.
+
+def _sel(mask, a, b):
+    """Per-learner select over stacked trees: leaf rows where the (L,)
+    ``mask`` is set come from ``a``, the rest from ``b``; ``a`` when
+    there is no mask."""
+    if mask is None:
+        return a
+
+    def one(x, y):
+        m = (mask > 0).reshape((-1,) + (1,) * (x.ndim - 1))
+        return jnp.where(m, x, y)
+    return jax.tree.map(one, a, b)
+
+
+def _reseed_rejoiners(params, faults):
+    """Rejoining learners re-enter at the incumbents' consensus mean —
+    elastic membership never resurrects a crashed learner's dead weights
+    (docs/fault_tolerance.md)."""
+    if faults is None:
+        return params
+    rejoin = faults["rejoin"]
+    incumbent = faults["active"] * (1.0 - rejoin)
+    n_inc = jnp.maximum(jnp.sum(incumbent), 1.0)
+
+    def one(w):
+        wf = w.astype(jnp.float32)
+        inc = incumbent.reshape((-1,) + (1,) * (w.ndim - 1))
+        mu = jnp.sum(wf * inc, axis=0, keepdims=True) / n_inc
+        rj = (rejoin > 0).reshape((-1,) + (1,) * (w.ndim - 1))
+        return jnp.where(rj, mu, wf).astype(w.dtype)
+
+    return jax.tree.map(one, params)
+
+
+def _frame_weighted(loss_l, g_l, lbatch, gmask, n_act):
+    """Frame-weighted aggregation: each learner's masked-mean gradient
+    is scaled by its valid-frame share among the contributors (``gmask``;
+    everyone without a plan), so the uniform 1/L combination (sc_psgd
+    mixing) — and proportionally the sd/ad_psgd ring updates — equals the
+    gradient of the GLOBAL masked loss over the contributors:
+    sum_l f_l g_l / sum_l f_l.  Returns (loss, g_l), the loss being the
+    frame-weighted mean.  Rectangular batches without a plan keep their
+    gradients and the plain mean loss."""
+    lengths = lbatch.get("lengths") if isinstance(lbatch, dict) else None
+    if lengths is None and gmask is None:
+        return jnp.mean(loss_l), g_l
+    if lengths is None:
+        frames = jnp.ones(loss_l.shape, jnp.float32)
+    else:
+        frames = jnp.sum(lengths.astype(jnp.float32),
+                         axis=tuple(range(1, lengths.ndim)))
+    if gmask is None:
+        mean = jnp.mean(frames)
+    else:
+        frames = gmask * frames
+        mean = jnp.sum(frames) / n_act
+    w = frames / jnp.maximum(mean, 1e-6)
+    g_l = jax.tree.map(
+        lambda g: (g.astype(jnp.float32)
+                   * w.reshape((-1,) + (1,) * (g.ndim - 1))
+                   ).astype(g.dtype), g_l)
+    return (jnp.sum(loss_l * frames)
+            / jnp.maximum(jnp.sum(frames), 1e-6)), g_l
+
+
 def make_train_step(strategy: Strategy, loss_fn: Callable,
                     optimizer: Optimizer, lr_schedule: Callable,
                     *, n_learners: int = 1, microbatches: int = 1,
                     with_consensus: bool = False, pre_split: bool = False,
                     transport: Optional[Transport] = None,
-                    with_grad_norm: bool = False, shard=None):
+                    with_grad_norm: bool = False, shard=None,
+                    fault_plan=None):
     """Build the jittable train step.
 
     loss_fn(params, batch) -> scalar, over UNstacked params/batch.
@@ -390,6 +468,22 @@ def make_train_step(strategy: Strategy, loss_fn: Callable,
     over that mesh axis (module docstring) — the learner axis for
     replicated strategies, the batch axis otherwise.
 
+    ``fault_plan`` (a :class:`repro.core.faults.FaultPlan`) makes the
+    step fault-tolerant, ``step(state, batch, faults)``, with ``faults``
+    one :meth:`FaultPlan.step_inputs` dict (traced: one compile covers
+    any schedule) and the state from :func:`init_state` with the same
+    plan.  Mixing then runs over the live set through
+    :meth:`Transport.make_elastic_mixer` (dead learners frozen
+    bit-for-bit); rejoiners re-enter at the incumbents' consensus mean
+    with a fresh optimizer state; learners that do not contribute apply
+    no gradient, keep their optimizer state and grow stale; frame
+    weights renormalize over the contributors.  The normative text is
+    docs/fault_tolerance.md.  With everyone active the trajectory
+    matches the plan-less step to f32 matmul tolerance (a matrix
+    contraction where the plain mixer uses rolls/means).  Only
+    replicated strategies take a plan.  Without one the step is
+    ``step(state, batch)`` and traces no mask.
+
     The returned function is named ``train_step``, so its jit's HLO
     module (``jit_train_step``) and compile records say which program
     they are; its parts run under ``jax.named_scope`` ``grad``,
@@ -398,8 +492,19 @@ def make_train_step(strategy: Strategy, loss_fn: Callable,
     """
     transport = transport if transport is not None \
         else default_transport(strategy)
-    mix = (transport.make_mixer(n_learners) if strategy.replicated
-           else None)
+    if not strategy.replicated:
+        if fault_plan is not None:
+            raise ValueError(
+                f"strategy {strategy.name!r} is not replicated: elastic "
+                f"membership needs a stacked learner axis to mask — use "
+                f"'sc_psgd_replicated' for an elastic allreduce baseline")
+        mixer = None
+    elif fault_plan is None:
+        mixer = transport.make_mixer(n_learners)
+    else:
+        mixer = transport.make_elastic_mixer(
+            n_learners, fault_seed=fault_plan.seed,
+            with_corruption=fault_plan.corrupt_prob > 0)
 
     def grad_one(params, batch):
         return _accumulated_grad(loss_fn, params, batch, microbatches)
@@ -407,7 +512,11 @@ def make_train_step(strategy: Strategy, loss_fn: Callable,
     learner_grads = _learner_grads(grad_one, shard)
     data_parallel_grad = _data_parallel_grad(grad_one, shard)
 
-    def train_step(state, batch):
+    def train_step(state, batch, faults=None):
+        if (faults is None) != (fault_plan is None):
+            raise TypeError("a step built with a fault plan takes one "
+                            "FaultPlan.step_inputs dict per call; a step "
+                            "built without one takes none")
         lr = lr_schedule(state["step"])
         metrics = {}
 
@@ -429,283 +538,60 @@ def make_train_step(strategy: Strategy, loss_fn: Callable,
                     metrics["grad_norm"] = _grad_norm(g)
             return out, metrics
 
-        lbatch = batch if pre_split else split_learner_batch(batch, n_learners)
-        grad_at = state["prev_params"] if strategy.stale else state["params"]
-        with jax.named_scope("grad"):
-            loss_l, g_l = learner_grads(grad_at, lbatch)
-        if isinstance(lbatch, dict) and "lengths" in lbatch:
-            # frame-weighted aggregation: each learner's masked-mean
-            # gradient is scaled by its valid-frame share, so the uniform
-            # 1/L combination (sc_psgd mixing) — and proportionally the
-            # sd/ad_psgd ring updates — equals the gradient of the GLOBAL
-            # masked loss:  sum_l f_l g_l / sum_l f_l.
-            frames = jnp.sum(lbatch["lengths"].astype(jnp.float32),
-                             axis=tuple(range(1, lbatch["lengths"].ndim)))
-            w = frames / jnp.maximum(jnp.mean(frames), 1e-6)
-            g_l = jax.tree.map(
-                lambda g: (g.astype(jnp.float32)
-                           * w.reshape((-1,) + (1,) * (g.ndim - 1))
-                           ).astype(g.dtype), g_l)
-            metrics["loss"] = (jnp.sum(loss_l * frames)
-                               / jnp.maximum(jnp.sum(frames), 1e-6))
-        else:
-            metrics["loss"] = jnp.mean(loss_l)
-        if with_grad_norm:
-            with jax.named_scope("grad_norm"):
-                metrics["grad_norm"] = jnp.mean(_grad_norm_stacked(g_l))
-
-        comm = state.get("comm", {})
-        wire_bytes = jnp.float32(transport.wire_bytes(state["params"]))
-        if strategy.block_size:
-            # BMUF: local SGD inside a block; blockwise model-update
-            # filtering at block boundaries.  The block sync goes through
-            # the substrate, so e.g. int8 block sync is one config away.
-            with jax.named_scope("update"):
-                upd_params, opt = jax.vmap(
-                    optimizer.update, in_axes=(0, 0, 0, None)
-                )(g_l, state["opt"], state["params"], lr)
-            step_no = state["step"] + 1
-            is_sync = (step_no % strategy.block_size) == 0
-
-            def do_sync(args):
-                params, anchor, mom, comm = args
-                with jax.named_scope("mixing"):
-                    avg, comm = mix(params, step_no, comm)
-                delta = jax.tree.map(
-                    lambda a, b: (a.astype(jnp.float32)
-                                  - b.astype(jnp.float32)), avg, anchor)
-                mom = jax.tree.map(
-                    lambda m, d: strategy.block_momentum * m
-                    + strategy.block_lr * d, mom, delta)
-                new = jax.tree.map(
-                    lambda b, m: (b.astype(jnp.float32) + m).astype(b.dtype),
-                    anchor, mom)
-                return new, new, mom, comm
-
-            def no_sync(args):
-                params, anchor, mom, comm = args
-                return params, anchor, mom, comm
-
-            new_params, anchor, mom, comm = jax.lax.cond(
-                is_sync, do_sync, no_sync,
-                (upd_params, state["anchor"], state["block_mom"], comm))
-            out = {"params": new_params, "opt": opt, "step": step_no,
-                   "anchor": anchor, "block_mom": mom}
-            metrics["wire_bytes"] = jnp.where(is_sync, wire_bytes, 0.0)
-        else:
-            # Eq. 14: mixing of the current iterate is data-independent of
-            # the gradient (evaluated at prev iterate when stale) -> XLA can
-            # schedule the collective concurrently with compute; chunked
-            # buckets (transport.bucket_bytes) deepen that interleaving.
-            with jax.named_scope("mixing"):
-                mixed, comm = mix(state["params"], state["step"], comm)
-            with jax.named_scope("update"):
-                new_params, opt = jax.vmap(
-                    optimizer.update, in_axes=(0, 0, 0, None)
-                )(g_l, state["opt"], mixed, lr)
-            out = {"params": new_params, "opt": opt,
-                   "step": state["step"] + 1}
-            metrics["wire_bytes"] = wire_bytes
-
-        if "comm" in state:
-            out["comm"] = comm
-        if strategy.stale:
-            out["prev_params"] = state["params"]
-        if with_consensus:
-            with jax.named_scope("consensus"):
-                metrics["consensus"] = consensus_distance(out["params"])
-        return out, metrics
-
-    return train_step
-
-
-# ---------------------------------------------------------------------------
-# Elastic (fault-tolerant) train step
-# ---------------------------------------------------------------------------
-
-def _sel(mask, a, b):
-    """Per-learner select over stacked trees: leaf rows where the (L,)
-    ``mask`` is set come from ``a``, the rest from ``b``."""
-    def one(x, y):
-        m = (mask > 0).reshape((-1,) + (1,) * (x.ndim - 1))
-        return jnp.where(m, x, y)
-    return jax.tree.map(one, a, b)
-
-
-def _reseed_rejoiners(params, rejoin, incumbent):
-    """Rejoining learners re-enter at the incumbents' consensus mean —
-    elastic membership never resurrects a crashed learner's dead weights
-    (docs/fault_tolerance.md)."""
-    n_inc = jnp.maximum(jnp.sum(incumbent), 1.0)
-
-    def one(w):
-        wf = w.astype(jnp.float32)
-        inc = incumbent.reshape((-1,) + (1,) * (w.ndim - 1))
-        mu = jnp.sum(wf * inc, axis=0, keepdims=True) / n_inc
-        rj = (rejoin > 0).reshape((-1,) + (1,) * (w.ndim - 1))
-        return jnp.where(rj, mu, wf).astype(w.dtype)
-
-    return jax.tree.map(one, params)
-
-
-def _masked_consensus(params, active):
-    """Consensus distance over the ACTIVE learners only (a crashed
-    learner's frozen replica is cluster weather, not disagreement)."""
-    n_act = jnp.maximum(jnp.sum(active), 1.0)
-
-    def one(w):
-        if w.ndim == 0 or w.shape[0] == 1:
-            return jnp.float32(0.0), jnp.float32(1.0)
-        wf = w.astype(jnp.float32)
-        a = active.reshape((-1,) + (1,) * (w.ndim - 1))
-        mu = jnp.sum(wf * a, axis=0, keepdims=True) / n_act
-        per = jnp.float32(wf.size) / wf.shape[0]
-        return jnp.sum(jnp.square(wf - mu) * a), n_act * per
-
-    parts = [one(w) for w in jax.tree.leaves(params)]
-    num = sum(p[0] for p in parts)
-    den = sum(p[1] for p in parts)
-    return jnp.sqrt(num / den)
-
-
-def init_elastic_state(strategy: Strategy, params, optimizer: Optimizer,
-                       transport: Optional[Transport] = None):
-    """:func:`init_state` plus the per-learner staleness counters (steps
-    since the learner last contributed a gradient) that drive
-    staleness-aware mixing weights."""
-    state = init_state(strategy, params, optimizer, transport)
-    state["staleness"] = jnp.zeros((_learner_dim(params),), jnp.int32)
-    return state
-
-
-def make_elastic_train_step(strategy: Strategy, loss_fn: Callable,
-                            optimizer: Optimizer, lr_schedule: Callable,
-                            *, n_learners: int, microbatches: int = 1,
-                            with_consensus: bool = False,
-                            pre_split: bool = False,
-                            transport: Optional[Transport] = None,
-                            fault_seed: int = 0,
-                            with_corruption: bool = False,
-                            with_grad_norm: bool = False, shard=None):
-    """Build the fault-tolerant variant of :func:`make_train_step`:
-
-        ``step(state, batch, faults) -> (state', metrics)``
-
-    where ``faults`` is one :meth:`repro.core.faults.FaultPlan.
-    step_inputs` dict (active/contrib/rejoin/edge_ok/corrupt arrays, all
-    traced — ONE jit compile covers any fault schedule).  Semantics
-    (normative text in docs/fault_tolerance.md):
-
-    * **membership** — mixing runs over the live set via the elastic
-      matrices (dead learners frozen bit-for-bit as identity rows);
-      rejoiners re-enter at the incumbents' consensus mean with a fresh
-      optimizer state and zero staleness.
-    * **stragglers/stalls** — a learner that is alive but not
-      contributing (``contrib`` = 0) still participates in mixing but
-      applies no gradient and keeps its optimizer state; its staleness
-      counter grows, and with ``transport.staleness_lambda`` > 0 its
-      mixing influence is damped by 1/(1 + λ·staleness).
-    * **aggregation** — frame weights renormalize over the contributing
-      learners: w_l = n_active·f_l/Σ_contrib f, so the mean applied
-      gradient equals the global masked gradient over contributors, and
-      the reported loss is the contributor frame-weighted mean.  The
-      all-inactive edge is clamped in-graph and rejected host-side
-      (:func:`check_active`, FaultPlan validation).
-    * **wire faults** — dropped edges return their mixing mass to the
-      diagonal; corrupted payloads (``with_corruption``) only poison
-      the peer view, never the local replica.
-
-    With the trivial mask (everyone active and contributing, no drops)
-    the trajectory matches :func:`make_train_step` to f32 matmul
-    tolerance — the elastic path mixes via an explicit matrix
-    contraction where the plain path uses rolls/means.
-
-    Only replicated strategies can be elastic (non-replicated sc_psgd
-    has no learner axis to mask — use ``sc_psgd_replicated``).
-    Difference-coded wires (topk) are rejected by
-    :meth:`Transport.make_elastic_mixer`.  ``shard`` is the learner-axis
-    ``shard_map`` of :func:`make_train_step`, and the step's name and
-    scopes are its too.
-    """
-    if not strategy.replicated:
-        raise ValueError(
-            f"strategy {strategy.name!r} is not replicated: elastic "
-            f"membership needs a stacked learner axis to mask — use "
-            f"'sc_psgd_replicated' for an elastic allreduce baseline")
-    transport = transport if transport is not None \
-        else default_transport(strategy)
-    mix = transport.make_elastic_mixer(
-        n_learners, fault_seed=fault_seed, with_corruption=with_corruption)
-
-    def grad_one(params, batch):
-        return _accumulated_grad(loss_fn, params, batch, microbatches)
-
-    learner_grads = _learner_grads(grad_one, shard)
-
-    def train_step(state, batch, faults):
-        lr = lr_schedule(state["step"])
-        metrics = {}
-        active = faults["active"]
-        rejoin = faults["rejoin"]
-        gmask = active * faults["contrib"]
-        n_act = jnp.maximum(jnp.sum(active), 1.0)
-        incumbent = active * (1.0 - rejoin)
-
         # membership first: rejoiners re-enter at the incumbents' mean
-        params = _reseed_rejoiners(state["params"], rejoin, incumbent)
-        fresh_opt = jax.vmap(optimizer.init)(params)
-        opt = _sel(rejoin, fresh_opt, state["opt"])
-        staleness = jnp.where(rejoin > 0, 0, state["staleness"])
+        # with a fresh optimizer state and zero staleness
+        params, opt = state["params"], state["opt"]
+        active = gmask = n_act = staleness = None
+        if faults is not None:
+            active = faults["active"]
+            gmask = active * faults["contrib"]
+            n_act = jnp.maximum(jnp.sum(active), 1.0)
+            params = _reseed_rejoiners(params, faults)
+            opt = _sel(faults["rejoin"], jax.vmap(optimizer.init)(params),
+                       opt)
+            staleness = jnp.where(faults["rejoin"] > 0, 0,
+                                  state["staleness"])
 
         lbatch = batch if pre_split else split_learner_batch(batch, n_learners)
-        grad_at = params
-        prev = None
-        if strategy.stale:
-            prev = _reseed_rejoiners(state["prev_params"], rejoin, incumbent)
-            grad_at = prev
+        grad_at = (_reseed_rejoiners(state["prev_params"], faults)
+                   if strategy.stale else params)
         with jax.named_scope("grad"):
             loss_l, g_l = learner_grads(grad_at, lbatch)
-
-        if isinstance(lbatch, dict) and "lengths" in lbatch:
-            frames = jnp.sum(lbatch["lengths"].astype(jnp.float32),
-                             axis=tuple(range(1, lbatch["lengths"].ndim)))
-        else:
-            frames = jnp.ones((n_learners,), jnp.float32)
-        cframes = gmask * frames
-        csum = jnp.maximum(jnp.sum(cframes), 1e-6)
-        # mean-over-active of the applied gradients == the global masked
-        # gradient over the contributors (all-contributing rectangular
-        # batches give w == 1, the plain-path convention)
-        w = n_act * cframes / csum
-        g_l = jax.tree.map(
-            lambda g: (g.astype(jnp.float32)
-                       * w.reshape((-1,) + (1,) * (g.ndim - 1))
-                       ).astype(g.dtype), g_l)
-        metrics["loss"] = jnp.sum(loss_l * cframes) / csum
+        metrics["loss"], g_l = _frame_weighted(loss_l, g_l, lbatch, gmask,
+                                               n_act)
         if with_grad_norm:
             # mean applied-gradient norm over the contributors
             with jax.named_scope("grad_norm"):
                 norms = _grad_norm_stacked(g_l)
-                metrics["grad_norm"] = (jnp.sum(norms * gmask)
-                                        / jnp.maximum(jnp.sum(gmask), 1.0))
+                metrics["grad_norm"] = (
+                    jnp.mean(norms) if gmask is None else
+                    jnp.sum(norms * gmask) / jnp.maximum(jnp.sum(gmask), 1.0))
 
-        wire_bytes = (jnp.float32(transport.wire_bytes(params))
-                      * n_act / n_learners)
+        comm = state.get("comm", {})
+        wire_bytes = jnp.float32(transport.wire_bytes(params))
+        if faults is not None:
+            wire_bytes = wire_bytes * n_act / n_learners
 
-        def elastic_mix(p, step_no):
+        def mix(p, step_no, comm):
             with jax.named_scope("mixing"):
-                return mix(p, step_no, active, staleness,
-                           faults["edge_ok"], faults["corrupt"])
+                if faults is None:
+                    return mixer(p, step_no, comm)
+                # the elastic mixer carries no comm state (topk refused)
+                return mixer(p, step_no, active, staleness,
+                             faults["edge_ok"], faults["corrupt"]), comm
 
         if strategy.block_size:
-            # elastic BMUF: gated local SGD inside the block; at block
-            # boundaries the survivors sync through the elastic matrix
-            # while the dead keep params/anchor/momentum frozen
-            anchor = _reseed_rejoiners(state["anchor"], rejoin, incumbent)
-            mom = _sel(rejoin,
-                       jax.tree.map(lambda m: jnp.zeros_like(m),
-                                    state["block_mom"]),
-                       state["block_mom"])
+            # BMUF: local SGD inside a block; blockwise model-update
+            # filtering at block boundaries.  The block sync goes through
+            # the substrate, so e.g. int8 block sync is one config away.
+            # Under a plan, contributors run the local SGD, and at block
+            # boundaries the survivors sync while the dead keep
+            # params/anchor/momentum frozen.
+            anchor = _reseed_rejoiners(state["anchor"], faults)
+            mom = state["block_mom"]
+            if faults is not None:
+                mom = _sel(faults["rejoin"],
+                           jax.tree.map(jnp.zeros_like, mom), mom)
             with jax.named_scope("update"):
                 upd_params, new_opt = jax.vmap(
                     optimizer.update, in_axes=(0, 0, 0, None)
@@ -716,8 +602,8 @@ def make_elastic_train_step(strategy: Strategy, loss_fn: Callable,
             is_sync = (step_no % strategy.block_size) == 0
 
             def do_sync(args):
-                p, anchor, mom = args
-                avg = elastic_mix(p, step_no)
+                p, anchor, mom, comm = args
+                avg, comm = mix(p, step_no, comm)
                 delta = jax.tree.map(
                     lambda a, b: (a.astype(jnp.float32)
                                   - b.astype(jnp.float32)), avg, anchor)
@@ -728,16 +614,22 @@ def make_elastic_train_step(strategy: Strategy, loss_fn: Callable,
                     lambda b, m: (b.astype(jnp.float32) + m).astype(b.dtype),
                     anchor, new_mom)
                 return (_sel(active, new, p), _sel(active, new, anchor),
-                        _sel(active, new_mom, mom))
+                        _sel(active, new_mom, mom), comm)
 
-            new_params, anchor, mom = jax.lax.cond(
-                is_sync, do_sync, lambda args: args,
-                (upd_params, anchor, mom))
+            def no_sync(args):
+                return args
+
+            new_params, anchor, mom, comm = jax.lax.cond(
+                is_sync, do_sync, no_sync, (upd_params, anchor, mom, comm))
             out = {"params": new_params, "opt": new_opt, "step": step_no,
                    "anchor": anchor, "block_mom": mom}
             metrics["wire_bytes"] = jnp.where(is_sync, wire_bytes, 0.0)
         else:
-            mixed = elastic_mix(params, state["step"])
+            # Eq. 14: mixing of the current iterate is data-independent of
+            # the gradient (evaluated at prev iterate when stale) -> XLA can
+            # schedule the collective concurrently with compute; chunked
+            # buckets (transport.bucket_bytes) deepen that interleaving.
+            mixed, comm = mix(params, state["step"], comm)
             with jax.named_scope("update"):
                 upd_params, new_opt = jax.vmap(
                     optimizer.update, in_axes=(0, 0, 0, None)
@@ -751,19 +643,21 @@ def make_elastic_train_step(strategy: Strategy, loss_fn: Callable,
                    "step": state["step"] + 1}
             metrics["wire_bytes"] = wire_bytes
 
+        if "comm" in state:
+            out["comm"] = comm
         if strategy.stale:
             out["prev_params"] = params
-        if "comm" in state:            # unreachable for topk (mixer raises)
-            out["comm"] = state["comm"]
-        out["staleness"] = jnp.where(gmask > 0, 0, staleness + 1
-                                     ).astype(jnp.int32)
-        metrics["n_active"] = n_act
-        metrics["n_contrib"] = jnp.sum(gmask)
-        metrics["staleness_max"] = jnp.max(out["staleness"] * (active > 0))
+        if faults is not None:
+            out["staleness"] = jnp.where(gmask > 0, 0, staleness + 1
+                                         ).astype(jnp.int32)
+            metrics["n_active"] = n_act
+            metrics["n_contrib"] = jnp.sum(gmask)
+            metrics["staleness_max"] = jnp.max(out["staleness"]
+                                               * (active > 0))
         if with_consensus:
             with jax.named_scope("consensus"):
-                metrics["consensus"] = _masked_consensus(out["params"],
-                                                         active)
+                metrics["consensus"] = consensus_distance(out["params"],
+                                                          active)
         return out, metrics
 
     return train_step

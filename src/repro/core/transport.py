@@ -73,8 +73,7 @@ factors communication out of the strategies into one composable
 
 ``Transport.wire_bytes`` is the single source for wire-byte telemetry:
 analytic bytes SENT per learner per mixing round, from the leaf shapes
-and the codec — emitted into train metrics as ``wire_bytes`` and
-accounted per (strategy × wire) by ``benchmarks/run.py --only comm``.
+and the codec — emitted into train metrics as ``wire_bytes``.
 The accounting conventions (per-topology multipliers, codec overheads)
 are documented in docs/strategies.md.
 """

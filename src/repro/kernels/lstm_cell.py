@@ -107,8 +107,8 @@ boundary-stash size.  T that doesn't divide by K is zero-padded to the
 next multiple and the padded steps masked off via a synthesized
 ``lengths`` vector, so the chunked path always runs the masked kernels
 (lengths = T everywhere reproduces the dense recurrence exactly).
-:func:`stash_bytes` is the accounting single-source (benchmarks and the
-stash-size tests read it).
+:func:`stash_bytes` is the accounting single-source (the train CLI and
+the stash-size tests read it).
 
 Fused multi-layer stack (``blstm_stack_sequence``)
 --------------------------------------------------
@@ -278,10 +278,10 @@ def auto_block_b(B: int, D: int, H: int, itemsize: int, *, n_dir: int = 1,
 def stash_bytes(B: int, T: int, H: int, *, n_dir: int = 1,
                 stash_itemsize: int = 4, seq_chunk: int = 0) -> int:
     """Residual-stash HBM bytes of the training forward (the accounting
-    single-source for benchmarks/run.py --only longseq and the stash-size
-    tests).  Unchunked: post-activation gates (4H) + cell states (H) per
-    (row, step).  Chunked: only the (h, c) chunk-entry carries — 2H per
-    (row, chunk), ceil(T / seq_chunk) chunks after time padding."""
+    single-source for the train CLI's ``kernel/stash_bytes`` gauge and the
+    stash-size tests).  Unchunked: post-activation gates (4H) + cell
+    states (H) per (row, step).  Chunked: the (h, c) chunk-entry carries
+    only — 2H per (row, chunk), ceil(T / seq_chunk) chunks after padding."""
     if seq_chunk and seq_chunk > 0:
         n_chunks = -(-T // seq_chunk)
         return n_dir * B * n_chunks * 2 * H * stash_itemsize

@@ -1,10 +1,9 @@
 """Pallas TPU fused dense-MoE kernel (granite-style tiny experts).
 
-Motivated directly by the §Perf attention-ablation measurement
-(benchmarks/flash_projection.py): after the combine fusion, granite's
-residual memory term is the HBM round-trip of every expert's hidden
-activations — (tokens, E, d_ff) at d_ff=512, E=40 is a 5× inflation of the
-active work and none of it needs to leave VMEM.
+Motivated by an attention-ablation measurement: after the combine
+fusion, granite's residual memory term is the HBM round-trip of every
+expert's hidden activations — (tokens, E, d_ff) at d_ff=512, E=40 is a
+5× inflation of the active work and none of it needs to leave VMEM.
 
 This kernel keeps one token tile (x: tile_t × d) resident, walks experts on
 the inner grid axis streaming each expert's (wi, wg, wo) through VMEM once

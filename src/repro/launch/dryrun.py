@@ -13,8 +13,7 @@ on the production meshes:
 
 and records memory_analysis(), cost_analysis(), and the trip-count-correct
 HLO analysis (FLOPs / bytes / per-collective bytes) into
-``experiments/dryrun/<arch>__<shape>__<mesh>.json`` — the §Roofline tables
-are generated from these artifacts by ``benchmarks/roofline.py``.
+``experiments/dryrun/<arch>__<shape>__<mesh>.json``.
 
 Train shapes lower the arch's own distributed strategy (the paper's
 technique: learner replicas + ring mixing); multi-pod train uses the

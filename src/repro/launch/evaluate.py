@@ -16,8 +16,7 @@ runs the BLSTM forward over a held-out synthetic set (respecting the
   tokens/s + beam occupancy, the same conventions ``launch/serve.py``
   prints.
 
-Output is the ``name,value,derived`` CSV of benchmarks/run.py so rows
-drop straight into the paper-tables flow.
+Output is the shared ``name,value,derived`` CSV (``repro.obs.csv_row``).
 
   PYTHONPATH=src python -m repro.launch.train --arch swb2000-blstm \
       --reduced --learners 2 --strategy ad_psgd --steps 40 \

@@ -5,11 +5,11 @@ Drives one server of ``repro.launch.serve`` (LM or streaming ASR,
 picked by the arch family) through a deterministic
 :class:`repro.serving.Workload` trace with the priority-tiered
 admission controller, and prints the per-run SLO summary in the shared
-``name,value,derived`` CSV schema of ``launch/evaluate.py`` and
-``benchmarks/run.py``.  Virtual time by default — the whole overload
-scenario runs in milliseconds of model compute plus a deterministic
-clock, so the same seed reproduces every row; ``--wall`` switches to
-wall-clock timestamps for real measurements.
+``name,value,derived`` CSV schema of ``launch/evaluate.py``.  Virtual
+time by default — the whole overload scenario runs in milliseconds of
+model compute plus a deterministic clock, so the same seed reproduces
+every row; ``--wall`` switches to wall-clock timestamps for real
+measurements.
 
 PYTHONPATH=src python -m repro.launch.load --arch smollm-360m --reduced \
     --qps 2 --horizon 10 --slots 2 --max-len 32

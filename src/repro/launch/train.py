@@ -43,20 +43,18 @@ def setup_training(cfg, mesh, *, strategy_name: str = None,
                    lr_schedule=None, seed: int = 0, multi_pod: bool = False,
                    with_consensus: bool = False, kernel_impl: str = "jax",
                    microbatches: int = None, transport=None,
-                   elastic: bool = False, fault_seed: int = 0,
-                   with_corruption: bool = False,
-                   with_grad_norm: bool = False):
+                   fault_plan=None, with_grad_norm: bool = False):
     """Build sharded train state + jitted step for one arch on one mesh.
 
     ``transport`` overrides the communication substrate (topology × wire
     × bucketing); default: the cfg's ``comm_*`` knobs resolved against
     the strategy (see repro.core.transport and docs/strategies.md).
 
-    ``elastic=True`` builds the fault-tolerant step instead
-    (``ST.make_elastic_train_step``): it takes a third ``faults``
-    argument — one ``FaultPlan.step_inputs`` dict per step — and runs
-    the strategy under elastic membership with staleness-aware mixing
-    (docs/fault_tolerance.md).
+    A ``fault_plan`` (``repro.core.faults.FaultPlan``) builds the
+    fault-tolerant step (``ST.make_train_step``): it takes a third
+    ``faults`` argument — one ``FaultPlan.step_inputs`` dict per step —
+    and runs the strategy under elastic membership with staleness-aware
+    mixing (docs/fault_tolerance.md).
 
     When the mesh axis that holds the learners (replicated strategies) or
     the batch (plain data-parallel) spans several devices, the step
@@ -89,19 +87,11 @@ def setup_training(cfg, mesh, *, strategy_name: str = None,
     def loss_fn(params, batch):
         return model.loss_fn(params, batch, kernel_impl=kernel_impl)
 
-    if elastic:
-        step_fn = ST.make_elastic_train_step(
-            strategy, loss_fn, opt, lr_schedule,
-            n_learners=n_learners, microbatches=microbatches,
-            with_consensus=with_consensus, transport=transport,
-            fault_seed=fault_seed, with_corruption=with_corruption,
-            with_grad_norm=with_grad_norm, shard=shard)
-    else:
-        step_fn = ST.make_train_step(
-            strategy, loss_fn, opt, lr_schedule,
-            n_learners=n_learners, microbatches=microbatches,
-            with_consensus=with_consensus, transport=transport,
-            with_grad_norm=with_grad_norm, shard=shard)
+    step_fn = ST.make_train_step(
+        strategy, loss_fn, opt, lr_schedule,
+        n_learners=n_learners, microbatches=microbatches,
+        with_consensus=with_consensus, transport=transport,
+        with_grad_norm=with_grad_norm, shard=shard, fault_plan=fault_plan)
 
     pspecs = model.param_specs()
     lead = ((n_learners, "learner"),) if strategy.replicated else ()
@@ -114,12 +104,8 @@ def setup_training(cfg, mesh, *, strategy_name: str = None,
                 params = ST.stack_for_learners(params, n_learners)
             params = jax.tree.map(jax.device_put, params, param_shardings)
         with obs.span("setup/state"):
-            if elastic:
-                state = ST.init_elastic_state(strategy, params, opt,
-                                              transport=transport)
-            else:
-                state = ST.init_state(strategy, params, opt,
-                                      transport=transport)
+            state = ST.init_state(strategy, params, opt,
+                                  transport=transport, fault_plan=fault_plan)
         with obs.span("setup/jit"):
             jit_step = jax.jit(step_fn, donate_argnums=(0,))
 
@@ -318,11 +304,10 @@ def main(argv=None):
     # driven by one deterministic FaultPlan (docs/fault_tolerance.md)
     from repro.core.faults import (FaultPlan, parse_departures,
                                    parse_stragglers)
-    elastic = bool(args.fault_stragglers or args.fault_departures
-                   or args.fault_drop_prob or args.fault_stall_prob
-                   or args.fault_corrupt_prob)
     plan = None
-    if elastic:
+    if (args.fault_stragglers or args.fault_departures
+            or args.fault_drop_prob or args.fault_stall_prob
+            or args.fault_corrupt_prob):
         plan = FaultPlan(
             n_learners, seed=args.fault_seed,
             stragglers=parse_stragglers(args.fault_stragglers),
@@ -346,9 +331,7 @@ def main(argv=None):
         kernel_impl=args.kernel_impl,
         lr_schedule=paper_recipe(steps_per_epoch=max(args.steps // 16, 1),
                                  base_lr=0.05, peak_lr=0.2),
-        elastic=elastic, fault_seed=args.fault_seed,
-        with_corruption=args.fault_corrupt_prob > 0,
-        with_grad_norm=obs.enabled())
+        fault_plan=plan, with_grad_norm=obs.enabled())
     dev = jax.devices()[0]
     print(f"mesh {dict(mesh.shape)} over {dev.platform} ({dev.device_kind}); "
           f"kernel-impl {args.kernel_impl}"

@@ -111,7 +111,7 @@ def out_project(p, o):
 
 def attn_seq(q, k, v, *, causal: bool, window=None, q_chunk: int = 512,
              pos_offset=0, seq_shard: bool = False,
-             seq_shard_chunked: bool = False, batch_axis="", stub: bool = False):
+             seq_shard_chunked: bool = False, batch_axis=""):
     """q: (B,Sq,H,E), k/v: (B,Sk,KV,E).  window: scalar (traced ok); a
     window >= Sk is full attention.  Returns (B,Sq,H,E).
 
@@ -131,13 +131,6 @@ def attn_seq(q, k, v, *, causal: bool, window=None, q_chunk: int = 512,
     G, M = KV, H // KV
     scale = 1.0 / np.sqrt(E)
     k_pos = jnp.arange(Sk)
-
-    if stub:
-        # attention-ablated stand-in (o = q): zero score traffic/compute.
-        # Used ONLY by benchmarks/flash_projection.py to measure the
-        # non-attention traffic floor that bounds the Pallas flash kernel's
-        # projected roofline (never a model path).
-        return q
 
     asg = {1: "model", 4: None}
     if batch_axis:

@@ -82,7 +82,7 @@ def layer_windows(cfg, seq_len: int, *, long_context: bool = False):
 # ---------------------------------------------------------------------------
 
 def _attn_block(cfg, p, x, positions, window, *, cache=None, pos=None,
-                fwd_only: bool = False, batch_axis="", stub: bool = False):
+                fwd_only: bool = False, batch_axis=""):
     """Returns (out, (k,v)) — k/v are the new cache when decoding, else the
     full-seq K/V for cache construction."""
     seq_shard = cfg.attn_sharding == "seq"
@@ -91,7 +91,7 @@ def _attn_block(cfg, p, x, positions, window, *, cache=None, pos=None,
         o = A.attn_seq(q, k, v, causal=True, window=window,
                        seq_shard=seq_shard,
                        seq_shard_chunked=seq_shard and fwd_only,
-                       batch_axis=batch_axis, stub=stub)
+                       batch_axis=batch_axis)
         return A.out_project(p, o), (k, v)
     k_cache, v_cache = cache
     q, k, v = A.qkv_project(cfg, p, x, x, positions, positions)
@@ -128,8 +128,7 @@ def forward_seq(cfg, params, x, *, long_context: bool = False,
         if fam in ("dense", "moe", "vlm"):
             o, (k, v) = _attn_block(cfg, p["attn"], h, positions, window,
                                     fwd_only=collect_cache,
-                                    batch_axis=batch_axis,
-                                    stub=kernel_impl == "ablate_attn")
+                                    batch_axis=batch_axis)
             x = x + o
             if collect_cache:
                 cache_out = _pad_cache(k, v, cache_len)
@@ -142,8 +141,7 @@ def forward_seq(cfg, params, x, *, long_context: bool = False,
         elif fam == "hybrid":
             oa, (k, v) = _attn_block(cfg, p["attn"], h, positions, window,
                                      fwd_only=collect_cache,
-                                     batch_axis=batch_axis,
-                                     stub=kernel_impl == "ablate_attn")
+                                     batch_axis=batch_axis)
             os_, (conv_state, h_ssm) = S.mamba2_seq(cfg, p["ssm"], h,
                                                     kernel_impl=kernel_impl)
             x = x + _hybrid_combine(oa, os_).astype(x.dtype)

@@ -27,8 +27,7 @@ instrument is a shared do-nothing object and a span only a profiler
 annotation, so uninstrumented runs pay one method call per site and
 stay bit-identical — the
 property the recovery / transport-golden / paged≡dense exactness
-tests rely on (gated by ``benchmarks/run.py --only obs`` at ≤ 3%
-step overhead).
+tests rely on.
 
 Module-level helpers (:func:`event`, :func:`span`, :func:`metric_*`)
 always dispatch through the *current* sinks, so call sites never cache
@@ -197,7 +196,7 @@ CSV_HEADER = "name,value,derived"
 
 
 def csv_row(name, value, derived="") -> str:
-    """One row of the shared stats schema (evaluate/benchmarks/load)."""
+    """One row of the shared stats schema (evaluate/load)."""
     try:
         value = f"{float(value):.6g}"
     except (TypeError, ValueError):

@@ -9,8 +9,8 @@ prefill / BLSTM-forward service time) and each decode wave costs
 term).  No wall-clock sleeps ever happen, so a whole overload scenario
 runs in milliseconds of real time, the timeline is a pure function of
 ``(trace, cost model, scheduler)``, and re-running the same seed
-reproduces every timestamp exactly — which is what makes the capacity
-report of ``benchmarks/run.py --only load`` reproducible row-for-row.
+reproduces every timestamp exactly — which is what makes a capacity
+search (:mod:`repro.serving.capacity`) reproducible row-for-row.
 :class:`WallClock` swaps in for benches: ``now`` is real elapsed time,
 ``advance`` is a no-op (the real compute provides the delay) and idle
 gaps actually sleep until the next arrival.

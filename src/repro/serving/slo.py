@@ -9,8 +9,8 @@ Every serving-side surface reports through the same two primitives:
   serving loop stamp it as events happen.  Timestamps are whatever the
   loop's clock says — *virtual* seconds in tests/benches, wall seconds
   in ``--wall`` mode — so the same accounting code covers both.
-* ``name,value,derived`` CSV rows — the schema ``benchmarks/run.py``
-  and ``launch/evaluate.py`` already print; the formatting source now
+* ``name,value,derived`` CSV rows — the schema ``launch/evaluate.py``
+  already prints; the formatting source now
   lives in :mod:`repro.obs` (:func:`repro.obs.csv_row` /
   :func:`repro.obs.print_csv_rows`); this module re-exports them as
   deprecation shims (docs/serving.md §Report schema).

@@ -19,7 +19,7 @@ The traffic model mirrors what a fleet front-end sees (docs/serving.md):
 
 Everything is a pure function of ``(Workload, seed)``: the same config
 produces the identical arrival/length/tier trace, which is what makes
-the capacity report of ``benchmarks/run.py --only load`` reproducible
+a capacity search (:mod:`repro.serving.capacity`) reproducible
 row-for-row.  Draw order is fixed (gap, thinning coin, then length and
 tier for accepted arrivals) so the trace is stable under refactors that
 do not change the model.

@@ -68,9 +68,6 @@ def test_fault_plan_schedules():
     assert plan.step_inputs(60)["rejoin"][1] == 1.0
     assert plan.step_inputs(59)["rejoin"][1] == 0.0
     assert plan.step_inputs(500)["active"][2] == 0.0
-    # perfsim views
-    np.testing.assert_array_equal(plan.speed_factors(),
-                                  [4, 1, 1, 1, 1, 1, 1, 1])
 
 
 def test_fault_plan_validation():
@@ -189,33 +186,30 @@ def test_edge_mask_drops_and_stays_ds():
 # Elastic train step
 # ---------------------------------------------------------------------------
 
-def _no_faults(L):
-    return {k: jnp.asarray(v)
-            for k, v in FaultPlan(L).no_fault_inputs().items()}
-
-
 @pytest.mark.parametrize("name", ["sd_psgd", "ad_psgd",
                                   "sc_psgd_replicated", "downpour",
                                   "hring", "bmuf"])
 def test_elastic_step_matches_plain_without_faults(name):
-    """With everyone active and contributing, the elastic step walks the
-    plain step's trajectory (matrix contraction vs rolls: f32 matmul
-    tolerance, not bit-exact).  exp is excluded by design — its elastic
-    matrix is the symmetrized one-peer graph (transport docstring)."""
+    """With everyone active and contributing, the step built with a
+    fault plan walks the plan-less step's trajectory (matrix contraction
+    vs rolls: f32 matmul tolerance, not bit-exact).  exp is excluded by
+    design — its elastic matrix is the symmetrized one-peer graph
+    (transport docstring)."""
     s = ST.get_strategy(name)
     L = 8
     params = ST.stack_for_learners({"w": jnp.zeros((8,))}, L)
     tr = ST.default_transport(s)
+    plan = FaultPlan(L)
     st_p = ST.init_state(s, params, sgd(), tr)
-    st_e = ST.init_elastic_state(s, params, sgd(), tr)
+    st_e = ST.init_state(s, params, sgd(), tr, fault_plan=plan)
     plain = jax.jit(ST.make_train_step(s, loss_fn, sgd(), constant(0.05),
                                        n_learners=L, transport=tr))
-    el = jax.jit(ST.make_elastic_train_step(
-        s, loss_fn, sgd(), constant(0.05), n_learners=L, transport=tr))
-    nf = _no_faults(L)
+    el = jax.jit(ST.make_train_step(
+        s, loss_fn, sgd(), constant(0.05), n_learners=L, transport=tr,
+        fault_plan=plan))
     for k in range(40):
         st_p, _ = plain(st_p, data(k))
-        st_e, m = el(st_e, data(k), nf)
+        st_e, m = el(st_e, data(k), plan.step_inputs(k))
     np.testing.assert_allclose(np.asarray(st_e["params"]["w"]),
                                np.asarray(st_p["params"]["w"]), atol=2e-5)
     assert float(m["n_active"]) == L
@@ -234,10 +228,10 @@ def test_elastic_converges_under_straggler_and_crash():
     s = ST.get_strategy("ad_psgd")
     tr = Transport(topology="ring", staleness_lambda=0.2)
     params = ST.stack_for_learners({"w": jnp.zeros((8,))}, L)
-    state = ST.init_elastic_state(s, params, sgd(), tr)
-    el = jax.jit(ST.make_elastic_train_step(
+    state = ST.init_state(s, params, sgd(), tr, fault_plan=plan)
+    el = jax.jit(ST.make_train_step(
         s, loss_fn, sgd(), constant(0.05), n_learners=L, transport=tr,
-        with_consensus=True))
+        with_consensus=True, fault_plan=plan))
     for k in range(300):
         before = np.asarray(state["params"]["w"][1])
         state, m = el(state, data(k), {kk: jnp.asarray(v) for kk, v in
@@ -264,10 +258,10 @@ def test_elastic_converges_with_drops_corruption_bf16():
     s = ST.get_strategy("ad_psgd")
     tr = Transport(topology="ring", wire="bf16", staleness_lambda=0.1)
     params = ST.stack_for_learners({"w": jnp.zeros((8,))}, L)
-    state = ST.init_elastic_state(s, params, momentum(), tr)
-    el = jax.jit(ST.make_elastic_train_step(
+    state = ST.init_state(s, params, momentum(), tr, fault_plan=plan)
+    el = jax.jit(ST.make_train_step(
         s, loss_fn, momentum(), constant(0.02), n_learners=L, transport=tr,
-        fault_seed=3, with_corruption=True))
+        fault_plan=plan))
     for k in range(300):
         state, m = el(state, data(k), {kk: jnp.asarray(v) for kk, v in
                                        plan.step_inputs(k).items()})
@@ -279,10 +273,10 @@ def test_elastic_staleness_counters_track_stragglers():
     L = 4
     plan = FaultPlan(L, stragglers=(Straggler(0, 4),))
     s = ST.get_strategy("sd_psgd")
-    state = ST.init_elastic_state(s, ST.stack_for_learners(
-        {"w": jnp.zeros((8,))}, L), sgd())
-    el = jax.jit(ST.make_elastic_train_step(
-        s, loss_fn, sgd(), constant(0.05), n_learners=L))
+    state = ST.init_state(s, ST.stack_for_learners(
+        {"w": jnp.zeros((8,))}, L), sgd(), fault_plan=plan)
+    el = jax.jit(ST.make_train_step(
+        s, loss_fn, sgd(), constant(0.05), n_learners=L, fault_plan=plan))
     for k in range(6):
         state, m = el(state, data(k), {kk: jnp.asarray(v) for kk, v in
                                        plan.step_inputs(k).items()})
@@ -291,6 +285,44 @@ def test_elastic_staleness_counters_track_stragglers():
     st = np.asarray(state["staleness"])
     assert st[0] == 1 and (st[1:] == 0).all()
     assert int(m["n_contrib"]) == 3
+
+
+def test_setup_training_fault_plan_is_the_only_switch():
+    """``setup_training(fault_plan=...)`` is the seam between the two
+    steps: a tiny BLSTM AD-PSGD job with an everyone-active plan walks
+    the plan-less job's trajectory, and the plan-less job keeps the
+    plain state and the two-argument step."""
+    import dataclasses
+
+    from repro.configs import get_arch
+    from repro.data import make_dataset
+    from repro.launch.mesh import make_local_mesh
+    from repro.launch.train import setup_training
+
+    L = 2
+    cfg = dataclasses.replace(
+        get_arch("swb2000-blstm").reduced(), n_layers=1, lstm_hidden=16,
+        lstm_bottleneck=8, input_dim=8, vocab=16, param_dtype="float32")
+    mesh = make_local_mesh(devices=jax.devices()[:1])
+    plan = FaultPlan(L)
+    ds = make_dataset(cfg, seq_len=5, batch=4, seed=0)
+    jobs = {name: setup_training(cfg, mesh, strategy_name="ad_psgd",
+                                 n_learners=L, fault_plan=p)
+            for name, p in (("plain", None), ("plan", plan))}
+    assert "staleness" not in jobs["plain"][0]
+    assert "staleness" in jobs["plan"][0]
+    plain_state, plain_step, _ = jobs["plain"]
+    plan_state, plan_step, _ = jobs["plan"]
+    with jax.set_mesh(mesh):
+        for k in range(20):
+            batch = ds.batch_at(k)
+            plain_state, _ = plain_step(plain_state, batch)
+            plan_state, m = plan_step(plan_state, batch,
+                                      plan.step_inputs(k))
+    for a, b in zip(jax.tree.leaves(plain_state["params"]),
+                    jax.tree.leaves(plan_state["params"])):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=2e-5)
+    assert float(m["n_active"]) == L
 
 
 # ---------------------------------------------------------------------------
@@ -309,5 +341,6 @@ def test_elastic_rejects_topk_and_non_replicated():
     with pytest.raises(ValueError, match="difference-coded"):
         Transport(topology="ring", wire="topk").make_elastic_mixer(8)
     with pytest.raises(ValueError, match="not replicated"):
-        ST.make_elastic_train_step(ST.get_strategy("sc_psgd"), loss_fn,
-                                   sgd(), constant(0.1), n_learners=1)
+        ST.make_train_step(ST.get_strategy("sc_psgd"), loss_fn, sgd(),
+                           constant(0.1), n_learners=1,
+                           fault_plan=FaultPlan(1))

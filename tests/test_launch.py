@@ -65,9 +65,3 @@ def test_dryrun_cli_one_pair(tmp_path):
     assert rec["status"] == "ok"
     assert rec["chips"] == 512
     assert rec["roofline"]["bound_s"] > 0
-
-
-def test_benchmarks_cli_quick():
-    r = run(["benchmarks.run", "--only", "table2"])
-    assert r.returncode == 0, r.stderr[-2000:]
-    assert "table2/ad_psgd_speedup/slow100x" in r.stdout

@@ -211,22 +211,22 @@ def test_elastic_kill_and_resume_bit_exact(tmp_path):
     s = ST.get_strategy("ad_psgd")
     tr = Transport(topology="ring", wire="bf16", staleness_lambda=0.2)
     params = ST.stack_for_learners({"w": jnp.zeros((8,))}, L)
-    step = jax.jit(ST.make_elastic_train_step(
+    step = jax.jit(ST.make_train_step(
         s, loss_fn, momentum(), constant(0.05), n_learners=L,
-        transport=tr))
+        transport=tr, fault_plan=plan))
 
     def faults(k):
         return {kk: jnp.asarray(v) for kk, v in plan.step_inputs(k).items()}
 
-    ref = ST.init_elastic_state(s, params, momentum(), tr)
+    ref = ST.init_state(s, params, momentum(), tr, fault_plan=plan)
     for k in range(20):
         ref, m_ref = step(ref, data(k), faults(k))
 
-    state = ST.init_elastic_state(s, params, momentum(), tr)
+    state = ST.init_state(s, params, momentum(), tr, fault_plan=plan)
     for k in range(10):
         state, _ = step(state, data(k), faults(k))
     CK.save(str(tmp_path), 10, state)
-    like = ST.init_elastic_state(s, params, momentum(), tr)
+    like = ST.init_state(s, params, momentum(), tr, fault_plan=plan)
     state, at = CK.restore(str(tmp_path), like)
     for k in range(at, 20):
         state, m_res = step(state, data(k), faults(k))
