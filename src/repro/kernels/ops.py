@@ -11,20 +11,16 @@ import functools
 
 import jax
 
-from repro.kernels.flash_attention import flash_attention
+from repro.kernels.flash_attention import BLOCK_Q, flash_attention
 from repro.kernels.lstm_cell import blstm_sequence as _blstm_sequence
 from repro.kernels.lstm_cell import lstm_sequence as _lstm_sequence
 from repro.kernels.moe_dense import moe_dense as _moe_dense
 from repro.kernels.ssd_scan import ssd as _ssd
 
 
-@functools.partial(jax.jit, static_argnames=("causal", "window", "block_q",
-                                             "block_k", "q_offset"))
-def attention(q, k, v, *, causal: bool = True, window: int = 0,
-              block_q: int = 512, block_k: int = 512, q_offset: int = 0):
-    return flash_attention(q, k, v, causal=causal, window=window,
-                           block_q=block_q, block_k=block_k,
-                           q_offset=q_offset)
+@functools.partial(jax.jit, static_argnames=("causal", "block_q"))
+def attention(q, k, v, *, causal: bool = True, block_q: int = BLOCK_Q):
+    return flash_attention(q, k, v, causal=causal, block_q=block_q)
 
 
 @functools.partial(jax.jit, static_argnames=("reverse", "block_b",

@@ -1,10 +1,15 @@
 """Grouped-query attention with three execution paths:
 
-* ``attn_seq``    — full-sequence (train / prefill): q-chunked streaming
-  softmax so the score tensor never materializes at (S, S); causal and
-  sliding-window masks are applied per chunk.  This is the pure-JAX
-  counterpart of the Pallas flash kernel in ``repro.kernels.flash_attention``
-  (selected via ``impl='pallas'``).
+* ``attn_seq``    — full-sequence (train / prefill / cross-attention).
+  It takes the trainable Pallas kernel of ``repro.kernels.flash_attention``
+  (``attn_fwd`` / ``attn_bwd``, the scores of a query block never leave
+  VMEM) when :func:`takes_kernel` holds: on a TPU, with no window, no
+  sequence sharding and bf16 operands whose heads the kernel tiles
+  (whisper's encoder and decoder).  Otherwise it runs the XLA path: a
+  q-chunked softmax whose f32 score block per chunk is recomputed in the
+  backward by a per-chunk checkpoint, with causal and sliding-window
+  masks applied per chunk (the CPU, the transformer family's traced
+  per-layer windows, sequence-parallel layouts).
 * ``attn_decode`` — single-token step against a KV cache (serve path).
   ``impl='pallas'`` selects the decode-shaped streaming kernel in
   ``repro.kernels.decode_attention`` (same masks, online softmax over
@@ -21,12 +26,17 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.ad_checkpoint import checkpoint_name
 
 from repro import obs
+from repro.kernels import flash_attention as FA
 from repro.models.common import apply_rope
 from repro.sharding import ParamSpec
 
 NEG_INF = -1e30
+# checkpoint names of attn_seq's output and of the kernel's log-sum-exp,
+# for a caller's remat policy (repro.models.encdec.LAYER_POLICY)
+OUT_NAME, LSE_NAME = FA.OUT_NAME, FA.LSE_NAME
 
 
 def _constrain_dims(x, assignments):
@@ -109,17 +119,33 @@ def out_project(p, o):
 # Full-sequence attention (train / prefill / cross)
 # ---------------------------------------------------------------------------
 
-def attn_seq(q, k, v, *, causal: bool, window=None, q_chunk: int = 512,
-             pos_offset=0, seq_shard: bool = False,
-             seq_shard_chunked: bool = False, batch_axis=""):
-    """q: (B,Sq,H,E), k/v: (B,Sk,KV,E).  window: scalar (traced ok); a
-    window >= Sk is full attention.  Returns (B,Sq,H,E).
+def takes_kernel(backend: str, q, k, v, *, window, seq_shard) -> bool:
+    """Whether :func:`attn_seq` runs the Pallas kernel: on a TPU, with no
+    window and no sequence sharding, on bf16 q/k/v whose heads fill the
+    kernel's 128-lane blocks (``flash_attention.supported``)."""
+    return (backend == "tpu" and window is None and not seq_shard
+            and q.dtype == k.dtype == v.dtype == jnp.bfloat16
+            and FA.supported(q.shape[2], q.shape[3]))
 
-    The queries run in chunks of ``q_chunk`` positions; when Sq is not a
-    multiple of it, the last chunk is shorter (whisper's 1500-frame
-    encoder: two chunks of 512 and one of 476).  Each trace counts once
-    in the ``repro.obs`` counter ``models/attn_seq``, tagged ``ragged``
-    (a shorter last chunk) and ``causal``.
+
+def attn_seq(q, k, v, *, causal: bool, window=None, q_chunk: int = 512,
+             seq_shard: bool = False, seq_shard_chunked: bool = False,
+             batch_axis=""):
+    """q: (B,Sq,H,E), k/v: (B,Sk,KV,E).  window: scalar (traced ok); a
+    window >= Sk is full attention.  Returns (B,Sq,H,E), named
+    :data:`OUT_NAME` for a remat policy.
+
+    Where :func:`takes_kernel` holds (the backend it observes, the
+    window, the sharding, the operands) the Pallas kernel computes it
+    and its VJP recomputes the scores from the saved log-sum-exp
+    (:data:`LSE_NAME`).  Otherwise the queries run in chunks of
+    ``q_chunk`` positions, each chunk checkpointed so that the backward
+    recomputes its scores; when Sq is not a multiple of it, the last
+    chunk is shorter (whisper's 1500-frame encoder: two chunks of 512
+    and one of 476).  Each trace counts once in the ``repro.obs``
+    counter ``models/attn_seq``, tagged ``impl`` (``pallas``/``xla``),
+    ``ragged`` (a shorter last chunk, or on the kernel a padded last
+    query block) and ``causal``.
 
     seq_shard=True is the sequence-parallel mode (EXPERIMENTS.md §Perf):
     K/V are gathered to full head_dim (cheap: one (B,Sk,KV,E) gather per
@@ -128,6 +154,12 @@ def attn_seq(q, k, v, *, causal: bool, window=None, q_chunk: int = 512,
     size instead of replicating them."""
     B, Sq, H, E = q.shape
     Sk, KV = k.shape[1], k.shape[2]
+    if takes_kernel(jax.default_backend(), q, k, v, window=window,
+                    seq_shard=seq_shard):
+        obs.counter("models/attn_seq", impl="pallas",
+                    ragged=int(Sq % FA.block_rows(Sq) > 0),
+                    causal=int(causal)).inc()
+        return FA.flash_attention(q, k, v, causal=causal)
     G, M = KV, H // KV
     scale = 1.0 / np.sqrt(E)
     k_pos = jnp.arange(Sk)
@@ -158,15 +190,15 @@ def attn_seq(q, k, v, *, causal: bool, window=None, q_chunk: int = 512,
         # remove a transpose+copy of the scores; it measured 5% WORSE —
         # the partitioner preferred the head-major layout.)
         s = jnp.einsum("bsgme,btge->bgmst", qg, k) * scale
-        s = mask(s.astype(jnp.float32), pos_offset + jnp.arange(Sq))
+        s = mask(s.astype(jnp.float32), jnp.arange(Sq))
         p = jax.nn.softmax(s, axis=-1).astype(v.dtype)
         o = jnp.einsum("bgmst,btge->bsgme", p, v)
-        return o.reshape(B, Sq, G * M, E)
+        return checkpoint_name(o.reshape(B, Sq, G * M, E), OUT_NAME)
 
     qg = q.reshape(B, Sq, G, M, E)
     q_chunk = min(q_chunk, Sq)
     n_full, tail = divmod(Sq, q_chunk)
-    obs.counter("models/attn_seq", ragged=int(tail > 0),
+    obs.counter("models/attn_seq", impl="xla", ragged=int(tail > 0),
                 causal=int(causal)).inc()
 
     def one_chunk(start, size):
@@ -179,7 +211,7 @@ def attn_seq(q, k, v, *, causal: bool, window=None, q_chunk: int = 512,
             # iter 3).
             qs = _constrain_dims(qs, asg)
         s = jnp.einsum("bcgme,btge->bgmct", qs, k) * scale
-        s = mask(s.astype(jnp.float32), pos_offset + start + jnp.arange(size))
+        s = mask(s.astype(jnp.float32), start + jnp.arange(size))
         p = jax.nn.softmax(s, axis=-1).astype(v.dtype)
         return jnp.einsum("bgmct,btge->bcgme", p, v)
 
@@ -196,7 +228,7 @@ def attn_seq(q, k, v, *, causal: bool, window=None, q_chunk: int = 512,
     if tail:
         parts.append(one_chunk(n_full * q_chunk, tail))
     o = jnp.concatenate(parts, axis=1) if tail else parts[0]
-    return o.reshape(B, Sq, G * M, E)
+    return checkpoint_name(o.reshape(B, Sq, G * M, E), OUT_NAME)
 
 
 # ---------------------------------------------------------------------------

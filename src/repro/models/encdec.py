@@ -16,9 +16,11 @@ in the compiled ops' metadata.
 
 Under ``cfg.remat`` each encoder and decoder layer is rematerialised
 with :data:`LAYER_POLICY`: the backward keeps each attention's q/k/v
-projections, its output and its output projection, and recomputes the
-LayerNorms, residual adds and the FFN, and (inside ``attn_seq``'s own
-per-chunk checkpoint) the attention scores.
+projections, its output, the Pallas kernel's log-sum-exp (on a TPU) and
+its output projection, and recomputes the LayerNorms, residual adds and
+the FFN.  The attention scores are recomputed inside ``attn_seq``: by
+the kernel's backward from the log-sum-exp, or on the XLA path by its
+per-chunk checkpoint.
 """
 from __future__ import annotations
 
@@ -37,11 +39,14 @@ from repro.sharding import ParamSpec
 CONV_K = 3      # both front-end convolutions: kernel 3, padding 1
 
 # what a layer's backward keeps: each attention's q/k/v projections, its
-# output and its output projection.  The FFN's (B, S, d_ff) hidden would
-# take as much again, past what one chip holds beside four learners.
-ATTN_QKV, ATTN_OUT, ATTN_PROJ = "attn_qkv", "attn_out", "attn_proj"
+# output (named by attn_seq), the kernel's log-sum-exp, without which the
+# backward would rerun the attention forward, and the output projection.
+# The FFN's (B, S, d_ff) hidden would take as much again, past what one
+# chip holds beside four learners.
+ATTN_QKV, ATTN_PROJ = "attn_qkv", "attn_proj"
+ATTN_OUT, ATTN_LSE = A.OUT_NAME, A.LSE_NAME
 LAYER_POLICY = jax.checkpoint_policies.save_only_these_names(
-    ATTN_QKV, ATTN_OUT, ATTN_PROJ)
+    ATTN_QKV, ATTN_OUT, ATTN_LSE, ATTN_PROJ)
 
 
 def enc_layer_specs(cfg):
@@ -114,10 +119,11 @@ def frontend(params, mel):
 
 def _attention(cfg, p, xq, xkv, **kw):
     """Projections, ``attn_seq`` and the output projection, each output
-    named for :data:`LAYER_POLICY`; returns the output and (k, v)."""
+    named for :data:`LAYER_POLICY` (``attn_seq`` names its own); returns
+    the output and (k, v)."""
     q, k, v = (checkpoint_name(t, ATTN_QKV)
                for t in A.qkv_project(cfg, p, xq, xkv))
-    o = checkpoint_name(A.attn_seq(q, k, v, **kw), ATTN_OUT)
+    o = A.attn_seq(q, k, v, **kw)
     return checkpoint_name(A.out_project(p, o), ATTN_PROJ), (k, v)
 
 

@@ -31,14 +31,14 @@ def _mk(shape, dtype, i=0, scale=1.0):
     (2, 256, 6, 2, 64),      # GQA 3:1
     (1, 256, 8, 1, 128),     # MQA, 128 head_dim
 ])
-@pytest.mark.parametrize("window", [0, 64])
-def test_flash_attention(B, S, H, KV, E, dtype, window):
+@pytest.mark.parametrize("causal", [True, False])
+def test_flash_attention(B, S, H, KV, E, dtype, causal):
     q = _mk((B, S, H, E), dtype, 1)
     k = _mk((B, S, KV, E), dtype, 2)
     v = _mk((B, S, KV, E), dtype, 3)
-    out = flash_attention(q, k, v, causal=True, window=window,
-                          block_q=64, block_k=64, interpret=True)
-    expect = ref.attention_ref(q, k, v, causal=True, window=window)
+    out = flash_attention(q, k, v, causal=causal, block_q=128,
+                          interpret=True)
+    expect = ref.attention_ref(q, k, v, causal=causal)
     tol = 2e-2 if dtype == jnp.bfloat16 else 2e-5
     np.testing.assert_allclose(out.astype(np.float32),
                                expect.astype(np.float32),
@@ -46,11 +46,11 @@ def test_flash_attention(B, S, H, KV, E, dtype, window):
 
 
 def test_flash_attention_noncausal():
+    """Keys and queries of different lengths, one query block."""
     q = _mk((2, 128, 4, 64), jnp.bfloat16, 4)
-    k = _mk((2, 128, 4, 64), jnp.bfloat16, 5)
-    v = _mk((2, 128, 4, 64), jnp.bfloat16, 6)
-    out = flash_attention(q, k, v, causal=False, block_q=64, block_k=64,
-                          interpret=True)
+    k = _mk((2, 200, 4, 64), jnp.bfloat16, 5)
+    v = _mk((2, 200, 4, 64), jnp.bfloat16, 6)
+    out = flash_attention(q, k, v, causal=False, interpret=True)
     expect = ref.attention_ref(q, k, v, causal=False)
     np.testing.assert_allclose(out.astype(np.float32),
                                expect.astype(np.float32), atol=2e-2,
@@ -65,10 +65,94 @@ def test_flash_attention_matches_model_chunked_path():
     k = _mk((1, 256, 2, 64), jnp.bfloat16, 8)
     v = _mk((1, 256, 2, 64), jnp.bfloat16, 9)
     a = attn_seq(q, k, v, causal=True, q_chunk=64)
-    b = flash_attention(q, k, v, causal=True, block_q=64, block_k=64,
-                        interpret=True)
+    b = flash_attention(q, k, v, causal=True, block_q=128, interpret=True)
     np.testing.assert_allclose(a.astype(np.float32), b.astype(np.float32),
                                atol=3e-2, rtol=3e-2)
+
+
+def _attn_value_and_grads(fn, q, k, v, w):
+    """sum(fn(q, k, v) * w) and its q/k/v gradients."""
+    return jax.value_and_grad(
+        lambda q, k, v: jnp.sum(fn(q, k, v).astype(jnp.float32) * w),
+        argnums=(0, 1, 2))(q, k, v)
+
+
+def _assert_attn_close(got, want):
+    """Output sum and each gradient within bf16 rounding of the XLA path's
+    (each a few 1e-3 of the tensor's norm apart, by the scores the XLA
+    path rounds to bf16)."""
+    (lg, gg), (lw, gw) = got, want
+    np.testing.assert_allclose(float(lg), float(lw), rtol=2e-2, atol=0.5)
+    for a, b in zip(gg, gw):
+        a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
+        assert np.linalg.norm(a - b) <= 2e-2 * np.linalg.norm(b)
+
+
+@pytest.mark.parametrize("Sq,Sk,causal", [
+    (300, 300, False),       # ragged: 300 queries in blocks of 128
+    (256, 200, False),       # a key length off the 128 tiling
+    (300, 300, True),        # causal self-attention, ragged
+    (100, 300, False),       # cross-attention, Sq != Sk
+])
+def test_flash_attention_grads_match_xla_path(Sq, Sk, causal):
+    """The kernel's output and its q/k/v gradients (custom VJP, 128-row
+    query blocks) match ``attn_seq``'s XLA path (autodiff through its
+    chunked softmax) on the shapes whisper's attentions take in small."""
+    from repro.models.attention import attn_seq
+
+    q = _mk((2, Sq, 4, 64), jnp.bfloat16, 20)
+    k = _mk((2, Sk, 4, 64), jnp.bfloat16, 21)
+    v = _mk((2, Sk, 4, 64), jnp.bfloat16, 22)
+    w = _mk((2, Sq, 4, 64), jnp.float32, 23)
+    got = _attn_value_and_grads(
+        lambda *a: flash_attention(*a, causal=causal, block_q=128,
+                                   interpret=True), q, k, v, w)
+    want = _attn_value_and_grads(
+        lambda *a: attn_seq(*a, causal=causal, q_chunk=128), q, k, v, w)
+    _assert_attn_close(got, want)
+
+
+def test_flash_attention_grads_under_learner_vmap():
+    """Under ``jax.vmap`` over a learner axis (the train step's stacking)
+    each learner's output and gradients match its own XLA-path ones."""
+    from repro.models.attention import attn_seq
+
+    L = 3
+    q = _mk((L, 1, 200, 4, 64), jnp.bfloat16, 30)
+    k = _mk((L, 1, 200, 4, 64), jnp.bfloat16, 31)
+    v = _mk((L, 1, 200, 4, 64), jnp.bfloat16, 32)
+    w = _mk((L, 1, 200, 4, 64), jnp.float32, 33)
+
+    def per_learner(fn):
+        return jax.vmap(lambda *a: _attn_value_and_grads(fn, *a))(q, k, v, w)
+
+    got = per_learner(lambda *a: flash_attention(
+        *a, causal=True, block_q=128, interpret=True))
+    want = per_learner(lambda *a: attn_seq(*a, causal=True, q_chunk=128))
+    for i in range(L):
+        _assert_attn_close(jax.tree.map(lambda x: x[i], got),
+                           jax.tree.map(lambda x: x[i], want))
+
+
+@pytest.mark.parametrize("backend,window,seq_shard,dtype,H,E,takes", [
+    ("tpu", None, False, jnp.bfloat16, 20, 64, True),    # whisper
+    ("cpu", None, False, jnp.bfloat16, 20, 64, False),
+    ("tpu", 128, False, jnp.bfloat16, 20, 64, False),    # a window
+    ("tpu", None, True, jnp.bfloat16, 20, 64, False),    # sequence-sharded
+    ("tpu", None, False, jnp.float32, 20, 64, False),
+    ("tpu", None, False, jnp.bfloat16, 3, 64, False),    # half a block
+    ("tpu", None, False, jnp.bfloat16, 4, 128, True),
+])
+def test_attn_seq_kernel_dispatch(backend, window, seq_shard, dtype, H, E,
+                                  takes):
+    """``attn_seq`` takes the kernel only on a TPU, with no window, no
+    sequence sharding and bf16 operands whose heads fill 128 lanes."""
+    from repro.models.attention import takes_kernel
+
+    q = jax.ShapeDtypeStruct((2, 1500, H, E), dtype)
+    k = jax.ShapeDtypeStruct((2, 1500, H, E), dtype)
+    assert takes_kernel(backend, q, k, k, window=window,
+                        seq_shard=seq_shard) is takes
 
 
 # ---------------------------------------------------------------------------

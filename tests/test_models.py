@@ -273,9 +273,9 @@ def test_whisper_conv_front_end_matches_lax_conv():
 def test_whisper_step_names_its_layers_and_counts_attention():
     """The compiled training step carries the scopes frontend, encoder,
     decoder, self_attn, cross_attn and out_head in its op metadata, and
-    the ``models/attn_seq`` counter counts one trace of each attention:
-    the encoder's with a shorter last chunk, the decoder's causal self-
-    and plain cross-attention."""
+    the ``models/attn_seq`` counter counts one trace of each attention,
+    all on the XLA path on the CPU: the encoder's with a shorter last
+    chunk, the decoder's causal self- and plain cross-attention."""
     import re
 
     from repro import obs
@@ -285,12 +285,13 @@ def test_whisper_step_names_its_layers_and_counts_attention():
     try:
         hlo = jax.jit(jax.value_and_grad(model.loss_fn)).lower(
             params, batch).compile().as_text()
-        counts = {(r["tags"]["ragged"], r["tags"]["causal"]): r["value"]
+        counts = {(r["tags"]["impl"], r["tags"]["ragged"],
+                   r["tags"]["causal"]): r["value"]
                   for r in obs.get_metrics().snapshot()
                   if r["name"] == "models/attn_seq"}
     finally:
         obs.reset()
-    assert counts == {(1, 0): 1, (0, 1): 1, (0, 0): 1}
+    assert counts == {("xla", 1, 0): 1, ("xla", 0, 1): 1, ("xla", 0, 0): 1}
     for scope in ("frontend", "encoder", "decoder", "self_attn",
                   "cross_attn", "out_head"):
         assert re.search(rf'op_name="[^"]*[/(]{scope}[)/]', hlo), scope
@@ -399,6 +400,78 @@ def test_whisper_remat_counter_counts_each_stack(remat, policy):
     finally:
         obs.reset()
     assert counts == {("enc", policy): 1, ("dec", policy): 1}
+
+
+def _kernel_whisper(monkeypatch):
+    """A tiny whisper whose 2 heads of 64 fill one 128-lane block (d_model
+    192 keeps the projections' shapes apart from H x E), 2 encoder and 3
+    decoder layers, traced as on a TPU: ``attn_seq`` sees a TPU backend
+    and the Pallas kernels run in interpret mode."""
+    from repro.kernels import flash_attention as FA
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(FA, "_resolve_interpret", lambda i: True)
+    cfg = dataclasses.replace(
+        get_arch("whisper-large-v3").reduced(), n_layers=3, n_enc_layers=2,
+        d_model=192, n_heads=2, n_kv_heads=2, head_dim=64, d_ff=256,
+        vocab=96, n_mels=8, n_audio_ctx=136, n_text_ctx=16)
+    model = build_model(cfg)
+    params = init_spec_tree(model.param_specs(), RNG)
+    batch = {"mel": jax.random.normal(RNG, (B, 272, 8), jnp.float32),
+             "tokens": jnp.zeros((B, 12), jnp.int32),
+             "labels": jnp.ones((B, 12), jnp.int32)}
+    return model, params, batch
+
+
+def test_whisper_step_takes_the_attention_kernel_on_tpu(monkeypatch):
+    """Traced for a TPU, the training step runs every attention (the
+    encoder's self-attention, the decoder's causal self- and its cross-
+    attention) through the Pallas kernel, and the ``models/attn_seq``
+    counter reads ``impl=pallas`` for each, each query padded to its
+    128-row block; the loss is finite."""
+    from repro import obs
+
+    model, params, batch = _kernel_whisper(monkeypatch)
+    obs.configure()
+    try:
+        loss, _ = jax.jit(jax.value_and_grad(model.loss_fn))(params, batch)
+        counts = {(r["tags"]["impl"], r["tags"]["ragged"],
+                   r["tags"]["causal"]): r["value"]
+                  for r in obs.get_metrics().snapshot()
+                  if r["name"] == "models/attn_seq"}
+    finally:
+        obs.reset()
+    assert counts == {("pallas", 1, 0): 2, ("pallas", 1, 1): 1}
+    assert np.isfinite(float(loss))
+
+
+def test_whisper_remat_keeps_the_kernels_lse_not_scores(monkeypatch):
+    """On the kernel path the layer policy keeps, per attention, q, k, v
+    (named by the model), the kernel's feature-major output (B, H x E,
+    S) and its f32 log-sum-exp (B, H / 2, 2, S), and no score block:
+    the backward reruns neither the attention forward nor the scores
+    outside the kernel."""
+    import collections
+
+    from jax._src.ad_checkpoint import saved_residuals
+
+    model, params, batch = _kernel_whisper(monkeypatch)
+    res = saved_residuals(model.loss_fn, params, batch)
+    got = collections.Counter((tuple(a.shape), str(a.dtype)) for a, _ in res)
+    shapes = {s for s, _ in got}
+    # lse: encoder (2 layers, 136 frames), decoder self and cross (3
+    # layers, 12 tokens)
+    assert got[((2, B, 1, 2, 136), "float32")] == 1
+    assert got[((3, B, 1, 2, 12), "float32")] == 2
+    # the kernel's output: one per attention
+    assert got[((2, B, 128, 136), "bfloat16")] == 1
+    assert got[((3, B, 128, 12), "bfloat16")] == 2
+    # q, k, v of the encoder; self q, k, v and cross q of the decoder
+    assert got[((2, B, 136, 2, 64), "bfloat16")] == 3
+    assert got[((3, B, 12, 2, 64), "bfloat16")] == 4
+    assert got[((3, B, 136, 2, 64), "bfloat16")] == 2    # cross k, v
+    assert not [s for s in shapes if s[-2:] in
+                {(136, 136), (12, 12), (12, 136), (136, 12)}]
 
 
 def test_layer_windows_global_override():

@@ -18,12 +18,14 @@ process at a time may load the TPU library, and pytest-xdist workers all
 import this file.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.kernels import flash_attention as FA
 from repro.kernels import lstm_cell as LC
 from repro.kernels import softmax_ce as SCE
 
@@ -141,32 +143,24 @@ def test_softmax_ce_compiles(one_chip, rule):
     assert "softmax_ce_train" in hlo
 
 
-def _cell_step(device, n_learners):
-    """The ring AD-PSGD train step and its argument shapes as the 1-chip
-    training cell builds them (``setup_training``'s step for 4 learners
-    of 256 x 21 frames at published widths, Pallas kernels), on one
-    described chip."""
-    import dataclasses
-
-    from repro.configs import get_arch
+def _step_for(device, cfg, n_learners, kernel_impl, schedule, batch):
+    """The ring AD-PSGD train step of ``setup_training`` for
+    ``n_learners`` stacked learners of ``cfg`` on one described chip,
+    with its state's and ``batch``'s shapes ({name: (shape, dtype)})."""
     from repro.core import strategies as ST
     from repro.launch.mesh import make_local_mesh, rules_for
     from repro.models import build_model
     from repro.optim.optimizers import get_optimizer
-    from repro.optim.schedules import paper_recipe
     from repro.sharding import init_spec_tree, spec_tree_shardings
 
-    cfg = dataclasses.replace(get_arch("swb2000-blstm"),
-                              lstm_vmem_budget_mb=96)
     mesh = make_local_mesh(data=1, devices=[device])
     rules = rules_for(cfg, mesh)
     strategy, opt = ST.get_strategy("ad_psgd"), get_optimizer("sgd")
     transport = ST.transport_from_cfg(cfg, strategy)
     model = build_model(cfg)
     step = ST.make_train_step(
-        strategy, lambda p, b: model.loss_fn(p, b, kernel_impl="pallas"),
-        opt, paper_recipe(steps_per_epoch=1000, base_lr=0.05, peak_lr=0.2),
-        n_learners=n_learners, transport=transport)
+        strategy, lambda p, b: model.loss_fn(p, b, kernel_impl=kernel_impl),
+        opt, schedule, n_learners=n_learners, transport=transport)
     pspecs = model.param_specs()
     lead = ((n_learners, "learner"),)
     shardings = spec_tree_shardings(pspecs, rules, extra_leading=lead)
@@ -181,10 +175,30 @@ def _cell_step(device, n_learners):
         lambda s, sh=None: _sds(sh or one, s.shape, s.dtype), v,
         *([shardings] if k in ("params", "prev_params") else []))
         for k, v in jax.eval_shape(init).items()}
+    return mesh, step, state, {k: _sds(one, *v) for k, v in batch.items()}
+
+
+def _cell_step(device, n_learners):
+    """The 1-chip BLSTM training cell's step: 4 learners of 256 x 21
+    frames at published widths, Pallas kernels."""
+    import dataclasses
+
+    from repro.configs import get_arch
+    from repro.optim.schedules import paper_recipe
+
+    cfg = dataclasses.replace(get_arch("swb2000-blstm"),
+                              lstm_vmem_budget_mb=96)
     rows = 256 * n_learners
-    batch = {"features": _sds(one, (rows, T, 260), jnp.float32),
-             "labels": _sds(one, (rows, T), jnp.int32)}
-    return mesh, step, state, batch
+    return _step_for(
+        device, cfg, n_learners, "pallas",
+        paper_recipe(steps_per_epoch=1000, base_lr=0.05, peak_lr=0.2),
+        {"features": ((rows, T, 260), jnp.float32),
+         "labels": ((rows, T), jnp.int32)})
+
+
+def _kernel_names(hlo):
+    return sorted(re.match(r"\s*(?:ROOT )?%([\w-]+)\.\d+ = ", ln).group(1)
+                  for ln in hlo.splitlines() if KERNEL in ln)
 
 
 def test_train_step_names_its_kernels_and_scopes(topo, one_chip,
@@ -195,7 +209,6 @@ def test_train_step_names_its_kernels_and_scopes(topo, one_chip,
     output layer's, of its kernel too), and no array the size of the logits is left: none ends in
     the 32,000 states with more elements than the learners' dW."""
     import math
-    import re
 
     for kernels in (LC, SCE):
         monkeypatch.setattr(kernels, "_resolve_interpret",
@@ -205,12 +218,10 @@ def test_train_step_names_its_kernels_and_scopes(topo, one_chip,
         hlo = jax.jit(step, donate_argnums=(0,)).lower(
             state, batch).compile().as_text()
     assert hlo.startswith("HloModule jit_train_step")
-    kernels = [re.match(r"\s*(?:ROOT )?%([\w-]+)\.\d+ = ", ln).group(1)
-               for ln in hlo.splitlines() if KERNEL in ln]
     # six forward kernels (both directions of a layer), one backward
     # kernel per direction and layer, the fused output layer
-    assert sorted(kernels) == (["blstm_fwd"] * 6 + ["lstm_bwd"] * 12
-                               + ["softmax_ce_train"])
+    assert _kernel_names(hlo) == (["blstm_fwd"] * 6 + ["lstm_bwd"] * 12
+                                  + ["softmax_ce_train"])
     fusions = [ln for ln in hlo.splitlines()
                if re.match(r"\s*(?:ROOT )?%[\w.-]*fusion[\w.-]* = ", ln)]
     # the output layer's work is its kernel: its scope may sit on the
@@ -230,3 +241,72 @@ def test_train_step_names_its_kernels_and_scopes(topo, one_chip,
         if dims.endswith(",32000")
         and math.prod(int(d) for d in dims.split(",")) > dw}
     assert not logits, logits
+
+
+@pytest.mark.parametrize("sq,sk,causal", [(1500, 1500, False),
+                                          (448, 448, True),
+                                          (448, 1500, False)])
+def test_attention_kernels_compile(one_chip, sq, sk, causal):
+    """whisper-large-v3's three attentions at the cell's shapes (4
+    learners x 2 segments, 20 heads of 64): the encoder's 1500 frames,
+    the decoder's causal 448 tokens, and its cross-attention of 448
+    tokens over 1500 frames.  Under value_and_grad the forward and the
+    backward kernel compile: the whole key row of a head and a 256-row
+    query block's f32 scores fit VMEM, with the key length off the
+    128-lane tiling."""
+    L, B, H, E = 4, 2, 20, 64
+
+    def loss(q, k, v):
+        o = FA.flash_attention(q, k, v, causal=causal, interpret=False)
+        return jnp.sum(o.astype(jnp.float32))
+
+    args = [_sds(one_chip, (L, B, s, H, E), jnp.bfloat16)
+            for s in (sq, sk, sk)]
+    hlo = jax.jit(jax.vmap(jax.value_and_grad(loss, argnums=(0, 1, 2)))
+                  ).lower(*args).compile().as_text()
+    # a transform may prefix the instruction name: vmap_jvp_attn_fwd
+    names = _kernel_names(hlo)
+    assert len(names) == 2
+    assert [n for n in names if "attn_fwd" in n] != []
+    assert [n for n in names if "attn_bwd" in n] != []
+
+
+# temporaries of the whisper cell's step before its attention kernel
+# (``memory_analysis`` for a described v5e; PERF.md section 4)
+WHISPER_TEMP_BYTES = 7_715_267_072
+
+
+def test_whisper_train_step_takes_the_attention_kernels(topo, monkeypatch):
+    """The whisper cell's whole step (4 learners x 2 segments of 30 s,
+    4 + 4 layers at published widths), traced for a TPU: each attention
+    is one ``attn_fwd`` and one ``attn_bwd`` (encoder, decoder self and
+    cross; the layer remat keeps the log-sum-exp, so no forward kernel
+    reruns in the backward), no f32 score-shaped array of (..., 20 heads,
+    448 or more queries, 1500 keys) is left in the program, and its
+    planned temporaries do not grow."""
+    import dataclasses
+
+    from repro.configs import get_arch
+    from repro.optim.schedules import paper_recipe
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(FA, "_resolve_interpret", lambda i: False)
+    cfg = dataclasses.replace(get_arch("whisper-large-v3"), n_enc_layers=4,
+                              n_layers=4)
+    rows = 2 * 4
+    mesh, step, state, batch = _step_for(
+        topo.devices[0], cfg, 4, "jax",
+        paper_recipe(steps_per_epoch=100000, base_lr=0.01, peak_lr=0.01),
+        {"mel": ((rows, 3000, 128), jnp.float32),
+         "tokens": ((rows, 448), jnp.int32),
+         "labels": ((rows, 448), jnp.int32)})
+    with jax.set_mesh(mesh):
+        compiled = jax.jit(step, donate_argnums=(0,)).lower(
+            state, batch).compile()
+    hlo = compiled.as_text()
+    assert _kernel_names(hlo) == ["attn_bwd"] * 3 + ["attn_fwd"] * 3
+    scores = {dims for dims in re.findall(r"\bf32\[([\d,]+)\]", hlo)
+              if re.search(r"(^|,)20,(\d+,)*\d+,1500$", dims)
+              and int(dims.split(",")[-2]) >= 448}
+    assert not scores, scores
+    assert compiled.memory_analysis().temp_size_in_bytes <= WHISPER_TEMP_BYTES
